@@ -108,6 +108,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, block_q=128,
             jax.ShapeDtypeStruct((Sq + sq_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((Sq + sq_pad, 1), jnp.float32),
         ],
+        name="flash_attn_fwd",
         interpret=interpret,
     )(qq, kk, vv)
     out = out[:, :Sq].reshape(B, H, Sq, Dv)

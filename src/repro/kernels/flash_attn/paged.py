@@ -182,6 +182,7 @@ def _split_call(q, k_pages, v_pages, ptab, lens, kv_splits, interpret):
             jax.ShapeDtypeStruct((B, S, H, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, S, H, 1), jnp.float32),
         ],
+        name="paged_attn_split",
         interpret=_default_interpret(interpret),
     )(ptab.astype(jnp.int32), lens.astype(jnp.int32), q, k_pages, v_pages,
       head_mask, row_pos)
@@ -244,6 +245,7 @@ def _combine_call(mid_o, m, l, interpret):
         ],
         out_specs=pl.BlockSpec((1, H, Dv), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Dv), jnp.float32),
+        name="paged_attn_combine",
         interpret=_default_interpret(interpret),
     )(mid_o, m, l)
 

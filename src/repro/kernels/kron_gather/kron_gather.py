@@ -273,6 +273,7 @@ def _gather_call2(factors, ids, use_layernorm, block_b, interpret, out_dtype,
         with_stats=with_stats, quantized=scales is not None)
     outs = pl.pallas_call(
         kernel, grid=(Bp // block_b,), in_specs=in_specs,
+        name="kron_gather_fwd",
         out_specs=out_specs, out_shape=out_shape, interpret=interpret,
         compiler_params=C.compiler_params(interpret),
     )(*inputs)
@@ -302,6 +303,7 @@ def _bwd_call2(factors, ids, g32, stats, use_layernorm, block_b, interpret):
         kernel, grid=(Bp // block_b,), in_specs=in_specs,
         out_specs=[_whole(f.shape) for f in factors],
         out_shape=[jax.ShapeDtypeStruct(f.shape, jnp.float32) for f in factors],
+        name="kron_gather_bwd",
         interpret=interpret, compiler_params=C.compiler_params(interpret),
     )(*inputs)
     return list(dfactors)
@@ -394,6 +396,7 @@ def _gather_call(factors, ids, use_layernorm, block_b, interpret, out_dtype,
         in_specs=in_specs,
         out_specs=out_specs if with_stats else out_specs[0],
         out_shape=out_shape if with_stats else out_shape[0],
+        name="kron_gather_fwd",
         interpret=interpret,
     )(*inputs)
     if with_stats:
@@ -453,6 +456,7 @@ def kron_gather_bwd_pallas(
         in_specs=in_specs,
         out_specs=[pl.BlockSpec(f.shape, lambda i: (0, 0, 0)) for f in factors],
         out_shape=[jax.ShapeDtypeStruct(f.shape, jnp.float32) for f in factors],
+        name="kron_gather_bwd",
         interpret=interpret,
     )(*inputs)
     return list(dfactors)

@@ -242,6 +242,7 @@ def _ce_call2(factors, x, labels, vocab_size, blk, nb, nt, block_b,
         in_specs=[xs_spec, col, f1_spec, f2_spec],
         out_specs=[col] * 3,
         out_shape=[jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32)] * 3,
+        name="fused_kron_ce_fwd",
         interpret=interpret,
         compiler_params=C.compiler_params(interpret),
     )(C.chain2_x(x, q1, q2), labels[:, None].astype(jnp.int32), f1i, f2b)
@@ -265,6 +266,7 @@ def _ce_bwd_call2(factors, x, labels, g32, m32, l32, vocab_size, blk, nb, nt,
         out_shape=[jax.ShapeDtypeStruct(xs.shape, jnp.float32),
                    jax.ShapeDtypeStruct(f1i.shape, jnp.float32),
                    jax.ShapeDtypeStruct(f2b.shape, jnp.float32)],
+        name="fused_kron_ce_bwd",
         interpret=interpret,
         compiler_params=C.compiler_params(interpret),
     )(xs, labels[:, None].astype(jnp.int32), g32[:, None], m32[:, None],
@@ -338,6 +340,7 @@ def kron_ce_pallas(
         ],
         out_specs=[pl.BlockSpec((block_b,), lambda i, j: (i,))] * 3,
         out_shape=out_shape,
+        name="fused_kron_ce_fwd",
         interpret=interpret,
     )(x, labels, f0, *factors[1:])
     loss = (m + jnp.log(l) - ylog)[:B]
@@ -406,6 +409,7 @@ def kron_ce_bwd_pallas(
             jax.ShapeDtypeStruct(x.shape, jnp.float32),
             *[jax.ShapeDtypeStruct(f.shape, jnp.float32) for f in factors],
         ],
+        name="fused_kron_ce_bwd",
         interpret=interpret,
     )(x, labels, g32, m32, l32, f0, *factors[1:])
     dh = dx[:B, : h.shape[-1]]

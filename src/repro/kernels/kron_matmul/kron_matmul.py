@@ -180,6 +180,7 @@ def _matmul_call2(factors, x2, blk, nt, block_b, interpret, scales):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((blk, block_b, t2), lambda i, j: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((t1, Bp, t2), jnp.float32),
+        name="kron_matmul_fwd",
         interpret=interpret,
         compiler_params=C.compiler_params(interpret),
     )(*inputs)
@@ -209,6 +210,7 @@ def _matmul_bwd_call2(factors, x2, g32, blk, nt, block_b, interpret):
         out_shape=[jax.ShapeDtypeStruct(xs.shape, jnp.float32),
                    jax.ShapeDtypeStruct(f1i.shape, jnp.float32),
                    jax.ShapeDtypeStruct(f2b.shape, jnp.float32)],
+        name="kron_matmul_bwd",
         interpret=interpret,
         compiler_params=C.compiler_params(interpret),
     )(xs, g3, f1i, f2b)
@@ -282,6 +284,7 @@ def kron_matmul_pallas(
         out_specs=pl.BlockSpec((block_b, tile_cols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], nt * tile_cols),
                                        jnp.float32),
+        name="kron_matmul_fwd",
         interpret=interpret,
     )(*inputs)
     return out[:B]
@@ -339,6 +342,7 @@ def kron_matmul_bwd_pallas(
             jax.ShapeDtypeStruct(x2.shape, jnp.float32),
             *[jax.ShapeDtypeStruct(f.shape, jnp.float32) for f in factors],
         ],
+        name="kron_matmul_bwd",
         interpret=interpret,
     )(x2, g32, f0, *factors[1:])
     return dx[:B], [df0, *dfrest]

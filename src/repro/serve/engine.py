@@ -98,6 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.quant import dequantize_params, quantize_params
 from repro.fault import PreemptionHandler, StragglerWatchdog
@@ -151,6 +152,11 @@ class Request:
     # SLO stats, filled by the engine
     first_token_at: Optional[float] = None
     prefix_hit_pages: int = 0  # cached pages mapped at (re)admission
+    # bounds of the request's spans (repro.obs clock), kept by the engine:
+    # when it last entered the queue, and when it was first admitted
+    queued_t: float = dataclasses.field(default=0.0, init=False, repr=False)
+    admitted_t: Optional[float] = dataclasses.field(default=None, init=False,
+                                                   repr=False)
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -364,6 +370,8 @@ class ServingEngine:
         req.first_token_at = None
         req.prefix_hit_pages = 0
         req.submitted_at = self._clock()
+        req.queued_t = time.perf_counter()
+        req.admitted_t = None
         req.status = "queued"
         self.queue.append(req)
 
@@ -445,6 +453,11 @@ class ServingEngine:
                     return  # page budget exhausted: block FIFO (no skipping)
                 pages += got
             self.queue.popleft()
+            now = time.perf_counter()
+            obs.record("request.queue", req.queued_t, now, uid=req.uid,
+                       requeues=req.preemptions)
+            if req.admitted_t is None:
+                req.admitted_t = now
             self._admit_seq += 1
             self.slot_req[s] = req
             self.slot_seq[s] = self._admit_seq
@@ -573,6 +586,7 @@ class ServingEngine:
         assert req is not None
         req.preemptions += 1
         req.status = "queued"
+        req.queued_t = time.perf_counter()
         self.preemptions += 1
         self._release_slot(s)
         self.queue.appendleft(req)
@@ -611,6 +625,7 @@ class ServingEngine:
         req.nonfinite_strikes += 1
         req.preemptions += 1
         req.status = "queued"
+        req.queued_t = time.perf_counter()
         self._release_slot(s)
         self.queue.appendleft(req)
 
@@ -710,6 +725,8 @@ class ServingEngine:
         across preemptions), not tokens since the last admission."""
         if req.first_token_at is None:
             req.first_token_at = self._clock()
+            obs.record("request.prefill", req.admitted_t, time.perf_counter(),
+                       uid=req.uid)
         req.output.append(tok)
         if req.on_token is not None:
             try:
@@ -736,46 +753,49 @@ class ServingEngine:
         """Sample and emit for ``emitting`` slots, quarantining any slot
         whose logits row is not finite (max over the vocab catches both NaN
         and ±inf in one cheap (B,) transfer)."""
-        if self._injector is not None:
-            logits = self._injector.corrupt_logits(self, logits, emitting)
-        nxt = self._sample(logits)
-        finite = np.isfinite(np.asarray(jnp.max(logits, axis=-1)))
-        for s in emitting:
-            req = self.slot_req[s]
-            if req is None:
-                continue
-            if not finite[s]:
-                self._quarantine(s)
-            else:
-                self._emit(s, req, int(nxt[s]))
+        with obs.span("engine.sync"):
+            if self._injector is not None:
+                logits = self._injector.corrupt_logits(self, logits, emitting)
+            nxt = self._sample(logits)
+            finite = np.isfinite(np.asarray(jnp.max(logits, axis=-1)))
+        with obs.span("engine.emit"):
+            for s in emitting:
+                req = self.slot_req[s]
+                if req is None:
+                    continue
+                if not finite[s]:
+                    self._quarantine(s)
+                else:
+                    self._emit(s, req, int(nxt[s]))
 
     def _prefill_tick(self, stalled: set[int] = frozenset()):
         """Mixed tick: prefilling slots consume up to C prompt tokens; slots
         already decoding ride along as length-1 chunks (prefill_step is the
         stepwise decode for C==1), so prefill pressure never stalls them.
         Stalled slots keep lens 0 — their cache state does not advance."""
-        C = self.prefill_chunk
-        toks = np.zeros((self.B, C), np.int32)
-        lens = np.zeros((self.B,), np.int32)
-        was_decoding = [False] * self.B
-        for s in range(self.B):
-            if self.slot_req[s] is None or s in stalled:
-                continue
-            if self.slot_pending[s]:
-                n = min(C, len(self.slot_pending[s]))
-                for i in range(n):
-                    toks[s, i] = self.slot_pending[s].popleft()
-                lens[s] = n
-            else:
-                was_decoding[s] = True
-                toks[s, 0] = self._cur_tokens[s]
-                lens[s] = 1
-        if not lens.any():  # every live slot stalled: no model call
-            self.stalled_ticks += 1
+        if all(self.slot_req[s] is None or s in stalled for s in range(self.B)):
+            self.stalled_ticks += 1  # every live slot stalled: no model call
             return
-        logits, self.cache = self._model_call(
-            lambda: self._prefill(self.params, self.cache,
-                                  jnp.asarray(toks), jnp.asarray(lens)))
+        C = self.prefill_chunk
+        with obs.span("engine.dispatch"):
+            toks = np.zeros((self.B, C), np.int32)
+            lens = np.zeros((self.B,), np.int32)
+            was_decoding = [False] * self.B
+            for s in range(self.B):
+                if self.slot_req[s] is None or s in stalled:
+                    continue
+                if self.slot_pending[s]:
+                    n = min(C, len(self.slot_pending[s]))
+                    for i in range(n):
+                        toks[s, i] = self.slot_pending[s].popleft()
+                    lens[s] = n
+                else:
+                    was_decoding[s] = True
+                    toks[s, 0] = self._cur_tokens[s]
+                    lens[s] = 1
+            logits, self.cache = self._model_call(
+                lambda: self._prefill(self.params, self.cache,
+                                      jnp.asarray(toks), jnp.asarray(lens)))
         self.prefill_ticks += 1
         emitting = []
         for s in range(self.B):
@@ -790,9 +810,10 @@ class ServingEngine:
         self._guarded_emit(logits, emitting)
 
     def _decode_tick(self):
-        toks = jnp.asarray(self._cur_tokens)
-        logits, self.cache = self._model_call(
-            lambda: self._step(self.params, self.cache, toks))
+        with obs.span("engine.dispatch"):
+            toks = jnp.asarray(self._cur_tokens)
+            logits, self.cache = self._model_call(
+                lambda: self._step(self.params, self.cache, toks))
         self.decode_ticks += 1
         emitting = []
         for s in range(self.B):
@@ -809,34 +830,41 @@ class ServingEngine:
 
     def step(self):
         """One engine tick: one jitted model call for the whole batch (or a
-        pure bookkeeping tick when everything live is stalled)."""
-        t0 = time.time()
+        pure bookkeeping tick when everything live is stalled). The tick
+        and its phases are ``repro.obs`` spans; the tick's span times it."""
         tick = self._tick
         self._tick += 1
-        try:
-            if self._injector is not None:
-                self._injector.on_tick(self, tick)
-            if self._preempt_handler is not None and self._preempt_handler.preempted:
-                self._draining = True
-            self._expire()
-            self._admit()
-            stalled = self._grow()
-            live = [s for s in range(self.B) if self.slot_req[s] is not None]
-            if not live:
-                self.stalled_ticks += 1  # queue blocked on pages, or empty
-            else:
-                prefilling = any(self.slot_pending[s] for s in live)
-                if self.prefill_mode == "chunked" and (prefilling or stalled):
-                    self._prefill_tick(stalled)
+        ran = (self.prefill_ticks, self.decode_ticks)
+        with obs.span("engine.step") as sp:
+            try:
+                if self._injector is not None:
+                    self._injector.on_tick(self, tick)
+                if self._preempt_handler is not None and self._preempt_handler.preempted:
+                    self._draining = True
+                with obs.span("engine.admit"):
+                    self._expire()
+                    self._admit()
+                with obs.span("engine.grow"):
+                    stalled = self._grow()
+                live = [s for s in range(self.B) if self.slot_req[s] is not None]
+                if not live:
+                    self.stalled_ticks += 1  # queue blocked on pages, or empty
                 else:
-                    self._decode_tick()
-                if self.prefix_cache is not None:
-                    self._publish_full_pages()
-        except EngineStepError as e:
-            # the model cannot run even on the degraded rung: account for
-            # every request rather than losing them
-            self._fail_all_in_flight(f"step_failed: {e}")
-        dt = time.time() - t0
+                    prefilling = any(self.slot_pending[s] for s in live)
+                    if self.prefill_mode == "chunked" and (prefilling or stalled):
+                        self._prefill_tick(stalled)
+                    else:
+                        self._decode_tick()
+                    if self.prefix_cache is not None:
+                        with obs.span("engine.publish"):
+                            self._publish_full_pages()
+            except EngineStepError as e:
+                # the model cannot run even on the degraded rung: account for
+                # every request rather than losing them
+                self._fail_all_in_flight(f"step_failed: {e}")
+            sp.set(kind="prefill" if self.prefill_ticks > ran[0] else
+                   "decode" if self.decode_ticks > ran[1] else "stalled")
+        dt = sp.t1 - sp.t0
         self._busy_s += dt
         self.watchdog.observe(tick, dt)
 

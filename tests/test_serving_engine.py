@@ -1,10 +1,13 @@
 """Continuous-batching engine: correctness vs direct decode + scheduler
 invariants (tick accounting, page budget, slot isolation, random streams)."""
 
+from collections import Counter
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.configs import get_smoke
 from repro.models import model as MD
 from repro.serve.cache import NO_SLOT_AXIS, PageAllocator, slot_axes
@@ -176,6 +179,67 @@ def test_stats_fields(setup):
     assert st["prefill_ticks"] >= 2 and st["decode_ticks"] >= 3
     assert st["ticks"] == st["prefill_ticks"] + st["decode_ticks"]
     assert st["free_pages"] == st["page_capacity"] > 0  # all pages returned
+
+
+# ---------------------------------------------------------------------------
+# spans (repro.obs): tick phases and each request's queue wait and prefill
+# ---------------------------------------------------------------------------
+
+def _children(recs, parent):
+    return [r.name for r in recs if r.parent == parent.name
+            and parent.t0 <= r.t0 and r.t1 <= parent.t1]
+
+
+def test_tick_spans_in_phase_order(setup):
+    """Each tick is an ``engine.step`` span holding admit, grow, dispatch,
+    sync and emit in that order; its ``kind`` says which tick ran, and the
+    engine's busy time is the sum of these spans."""
+    cfg, params = setup
+    obs.reset()
+    eng = ServingEngine(cfg, params, batch_slots=2, max_len=64, prefill_chunk=4)
+    for i in range(3):
+        eng.submit(Request(uid=i, prompt=[i + 1, 5, 9, 2, 8], max_new_tokens=3))
+    res = eng.run_until_drained()
+    recs = obs.records()
+    steps = [r for r in recs if r.name == "engine.step"]
+    assert len(steps) == res.ticks
+    st = eng.stats()
+    kinds = Counter(r.attrs["kind"] for r in steps)
+    assert kinds == {"prefill": st["prefill_ticks"], "decode": st["decode_ticks"]}
+    for r in steps:
+        assert r.parent is None
+        assert _children(recs, r) == ["engine.admit", "engine.grow", "engine.dispatch",
+                                      "engine.sync", "engine.emit"], r
+    busy = sum(r.t1 - r.t0 for r in steps)
+    assert st["tokens_per_sec"] == pytest.approx(st["generated_tokens"] / busy)
+
+
+@pytest.mark.parametrize("pool", ["roomy", "tight"])
+def test_request_spans_split_ttft(setup, pool):
+    """A request's first ``request.queue`` span and its ``request.prefill``
+    span add up to its time to first token; the prefill starts where that
+    wait ends. A preempted request waits again, in a span of its own."""
+    cfg, params = setup
+    obs.reset()
+    kw = (dict(max_len=64) if pool == "roomy" else
+          dict(max_len=32, page_size=4, num_pages=3))  # forces preemption
+    eng = ServingEngine(cfg, params, batch_slots=2, prefill_chunk=4, **kw)
+    reqs = [Request(uid=i, prompt=[i + 1, 7, 9], max_new_tokens=5) for i in range(3)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_drained()
+    assert len(eng.done) == 3
+    recs = obs.records()
+    for req in reqs:
+        waits = [r for r in recs if r.name == "request.queue" and r.attrs["uid"] == req.uid]
+        (prefill,) = [r for r in recs
+                      if r.name == "request.prefill" and r.attrs["uid"] == req.uid]
+        assert [w.attrs["requeues"] for w in waits] == list(range(req.preemptions + 1))
+        assert prefill.t0 == waits[0].t1
+        split = (waits[0].t1 - waits[0].t0) + (prefill.t1 - prefill.t0)
+        assert split == pytest.approx(req.first_token_at - req.submitted_at, abs=2e-3)
+    preempted = sum(r.preemptions for r in reqs)
+    assert (preempted > 0) == (pool == "tight")
 
 
 # ---------------------------------------------------------------------------

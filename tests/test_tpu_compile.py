@@ -13,6 +13,7 @@ written for a described chip cannot be read back without one.
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,10 +72,16 @@ def no_persistent_cache():
 
 @pytest.fixture
 def compile_tpu(one_chip, no_persistent_cache):
-    def run(fn, *shapes):
+    """Compile ``fn`` for the described chip; every Mosaic kernel in the
+    program must carry the name ``kernel`` (the ``pallas_call``'s ``name=``),
+    which is what a device trace shows as the operation's name."""
+    def run(fn, *shapes, kernel):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+        names = re.findall(r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+                           text)
+        assert names, "no Mosaic kernel in the program"
+        assert all(re.fullmatch(rf"{kernel}(\.\d+)?", n) for n in names), names
     return run
 
 
@@ -116,14 +123,16 @@ def test_kron_gather_fwd(compile_tpu):
     assert bb % 8 == 0
     compile_tpu(lambda f0, f1, ids: kron_gather_pallas(
         [f0, f1], ids, block_b=bb, interpret=False),
-        _f32(*EMBED[0]), _f32(*EMBED[1]), ((TOKENS,), jnp.int32))
+        _f32(*EMBED[0]), _f32(*EMBED[1]), ((TOKENS,), jnp.int32),
+        kernel="kron_gather_fwd")
 
 
 def test_kron_gather_fwd_with_stats(compile_tpu):
     bb = _blocks("kron_gather", EMBED).block_b
     compile_tpu(lambda f0, f1, ids: kron_gather_fwd_pallas(
         [f0, f1], ids, block_b=bb, interpret=False),
-        _f32(*EMBED[0]), _f32(*EMBED[1]), ((TOKENS,), jnp.int32))
+        _f32(*EMBED[0]), _f32(*EMBED[1]), ((TOKENS,), jnp.int32),
+        kernel="kron_gather_fwd")
 
 
 def test_kron_gather_bwd(compile_tpu):
@@ -132,7 +141,8 @@ def test_kron_gather_bwd(compile_tpu):
     compile_tpu(lambda f0, f1, ids, g, st: kron_gather_bwd_pallas(
         [f0, f1], ids, g, st, block_b=bb, interpret=False),
         _f32(*EMBED[0]), _f32(*EMBED[1]), ((TOKENS,), jnp.int32),
-        _f32(TOKENS, CFG.d_model), _f32(TOKENS, 2, rank))
+        _f32(TOKENS, CFG.d_model), _f32(TOKENS, 2, rank),
+        kernel="kron_gather_bwd")
 
 
 def test_fused_kron_ce_fwd(compile_tpu):
@@ -142,7 +152,7 @@ def test_fused_kron_ce_fwd(compile_tpu):
         [f0, f1], h, y, CFG.vocab_size, t1_block=bc.t1_block,
         block_b=bc.block_b, interpret=False, return_stats=True),
         _f32(*HEAD[0]), _f32(*HEAD[1]), _f32(TOKENS, CFG.d_model),
-        ((TOKENS,), jnp.int32))
+        ((TOKENS,), jnp.int32), kernel="fused_kron_ce_fwd")
 
 
 def test_fused_kron_ce_bwd(compile_tpu):
@@ -151,7 +161,8 @@ def test_fused_kron_ce_bwd(compile_tpu):
         [f0, f1], h, y, m, l, g, CFG.vocab_size, t1_block=bc.t1_block,
         block_b=bc.block_b, interpret=False),
         _f32(*HEAD[0]), _f32(*HEAD[1]), _f32(TOKENS, CFG.d_model),
-        ((TOKENS,), jnp.int32), _f32(TOKENS), _f32(TOKENS), _f32(TOKENS))
+        ((TOKENS,), jnp.int32), _f32(TOKENS), _f32(TOKENS), _f32(TOKENS),
+        kernel="fused_kron_ce_bwd")
 
 
 def test_kron_matmul_fwd_head_logits(compile_tpu):
@@ -160,7 +171,7 @@ def test_kron_matmul_fwd_head_logits(compile_tpu):
     compile_tpu(lambda f0, f1, x: kron_matmul_pallas(
         [f0, f1], x, t1_block=CFG.head_vocab_tile, block_b=bc.block_b,
         interpret=False),
-        _f32(*HEAD[0]), _f32(*HEAD[1]), _f32(8, CFG.d_model))
+        _f32(*HEAD[0]), _f32(*HEAD[1]), _f32(8, CFG.d_model), kernel="kron_matmul_fwd")
 
 
 @pytest.mark.parametrize("name", sorted(LINEARS))
@@ -172,7 +183,7 @@ def test_kron_matmul_fwd(compile_tpu, name):
     compile_tpu(lambda f0, f1, x: kron_matmul_pallas(
         [f0, f1], x, t1_block=bc.t1_block, block_b=bc.block_b,
         interpret=False),
-        _f32(*fs[0]), _f32(*fs[1]), _f32(TOKENS, d_in))
+        _f32(*fs[0]), _f32(*fs[1]), _f32(TOKENS, d_in), kernel="kron_matmul_fwd")
 
 
 @pytest.mark.parametrize("name", sorted(LINEARS))
@@ -184,7 +195,8 @@ def test_kron_matmul_bwd(compile_tpu, name):
     compile_tpu(lambda f0, f1, x, g: kron_matmul_bwd_pallas(
         [f0, f1], x, g, t1_block=bc.t1_block, block_b=bc.block_b,
         interpret=False),
-        _f32(*fs[0]), _f32(*fs[1]), _f32(TOKENS, d_in), _f32(TOKENS, d_out))
+        _f32(*fs[0]), _f32(*fs[1]), _f32(TOKENS, d_in), _f32(TOKENS, d_out),
+        kernel="kron_matmul_bwd")
 
 
 # serving decode read: 8 slots at max_len 1056 (66 pages of 16 tokens),
@@ -200,10 +212,12 @@ def test_paged_split(compile_tpu):
     compile_tpu(lambda q, k, v, t, n: paged_attention_split_pallas(
         q, k, v, t, n, kv_splits=KV_SPLITS, interpret=False),
         ((SLOTS, H, DH), CFG.dtype), pool, pool,
-        ((SLOTS, PAGES_PER_SLOT), jnp.int32), ((SLOTS,), jnp.int32))
+        ((SLOTS, PAGES_PER_SLOT), jnp.int32), ((SLOTS,), jnp.int32),
+        kernel="paged_attn_split")
 
 
 def test_paged_combine(compile_tpu):
     part = (SLOTS, KVH, KV_SPLITS, H // KVH)
     compile_tpu(lambda o, m, l: combine_splits_pallas(o, m, l, interpret=False),
-                _f32(*part, DH), _f32(*part, 1), _f32(*part, 1))
+                _f32(*part, DH), _f32(*part, 1), _f32(*part, 1),
+                kernel="paged_attn_combine")
