@@ -14,6 +14,7 @@ inside a Pallas kernel body, in interpret mode, and in the pure-JAX oracles:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence
 
@@ -28,6 +29,8 @@ LN_EPS = 1e-5
 # order-2 bodies need more at the heads' widths (autotune sizes block_b
 # against ``autotune._BUDGET_BYTES``, well inside this).
 VMEM_LIMIT_BYTES = 96 << 20
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 def compiler_params(interpret: bool):
@@ -309,24 +312,24 @@ def chain_fused_forward(x: jax.Array, factors: Sequence) -> jax.Array:
     ``(B·Πt_{<n}, r·q_n) @ (r·q_n, t_n)`` and never materializes the
     ``(B, r, Πt)`` pre-sum tensor — the kron_matmul kernel's core trick
     (measured ~2× fwd on the bench arch shapes). Same output, bitwise-close
-    (fp32 accumulation either way).
+    (fp32 accumulation either way). Every contraction runs at
+    ``Precision.HIGHEST`` (the kernels' fp32 contract, also on the MXU).
     """
+    mm = functools.partial(jnp.einsum, precision=_HI,
+                           preferred_element_type=jnp.float32)
     q_dims, _ = factor_dims(factors)
     n = len(factors)
     b = x.shape[0]
     z = x.reshape((b,) + q_dims)
     if n == 1:
         # single factor: fold the rank sum straight into the one GEMM
-        return jnp.einsum("bq,rqt->bt", z, as_f32_factor(factors[0]),
-                          preferred_element_type=jnp.float32)
+        return mm("bq,rqt->bt", z, as_f32_factor(factors[0]))
     for i, f in enumerate(factors[:-1]):
         spec = "bq...,rqt->brt..." if i == 0 else "brq...,rqt->brt..."
-        z = jnp.einsum(spec, z, as_f32_factor(f),
-                       preferred_element_type=jnp.float32)
+        z = mm(spec, z, as_f32_factor(f))
         z = jnp.moveaxis(z, 2, 2 + (n - 1))
     # layout here: (B, r, q_n, t_1..t_{n-1}); contract q_n AND the rank axis
-    z = jnp.einsum("brq...,rqt->b...t", z, as_f32_factor(factors[-1]),
-                   preferred_element_type=jnp.float32)
+    z = mm("brq...,rqt->b...t", z, as_f32_factor(factors[-1]))
     return z.reshape(b, -1)
 
 
@@ -344,6 +347,8 @@ def chain_fused_vjp(
     the standard reverse sweep. Chain intermediates are recomputed, not
     saved (same rematerialization budget as the forward kernel).
     """
+    mm = functools.partial(jnp.einsum, precision=_HI,
+                           preferred_element_type=jnp.float32)
     q_dims, t_dims = factor_dims(factors)
     n = len(factors)
     b = x.shape[0]
@@ -351,13 +356,10 @@ def chain_fused_vjp(
 
     if n == 1:
         d = d_out  # (B, t_1)
-        rank = f32[0].shape[0]
         # y = Σ_r x·F_r: every rank slice sees the same cotangent
-        df = jnp.einsum("bq,bt->qt", x.reshape(b, -1).astype(jnp.float32), d,
-                        preferred_element_type=jnp.float32)
+        df = mm("bq,bt->qt", x.reshape(b, -1).astype(jnp.float32), d)
         dfs = [jnp.broadcast_to(df[None], f32[0].shape)]
-        dx = jnp.einsum("bt,qt->bq", d, jnp.sum(f32[0], axis=0),
-                        preferred_element_type=jnp.float32)
+        dx = mm("bt,qt->bq", d, jnp.sum(f32[0], axis=0))
         return dx, dfs
 
     zs = []
@@ -365,28 +367,22 @@ def chain_fused_vjp(
     for i, f in enumerate(f32[:-1]):
         zs.append(z)
         spec = "bq...,rqt->brt..." if i == 0 else "brq...,rqt->brt..."
-        z = jnp.einsum(spec, z, f, preferred_element_type=jnp.float32)
+        z = mm(spec, z, f)
         z = jnp.moveaxis(z, 2, 2 + (n - 1))
     # z layout: (B, r, q_n, t_1..t_{n-1}) — the fused last step's input
     dfactors: list = [None] * n
     d = d_out.reshape((b,) + t_dims)  # (B, t_1..t_n), no rank broadcast
-    dfactors[n - 1] = jnp.einsum("brq...,b...t->rqt", z, d,
-                                 preferred_element_type=jnp.float32)
-    d = jnp.einsum("b...t,rqt->brq...", d, f32[-1],
-                   preferred_element_type=jnp.float32)
+    dfactors[n - 1] = mm("brq...,b...t->rqt", z, d)
+    d = mm("b...t,rqt->brq...", d, f32[-1])
     # d is now in the post-step-(n−2) layout; the rest is chain_vjp's sweep
     for i in range(n - 2, -1, -1):
         d_moved = jnp.moveaxis(d, 2 + (n - 1), 2)  # t_i back to axis 2
         if i == 0:
-            dfactors[0] = jnp.einsum("bq...,brt...->rqt", zs[0], d_moved,
-                                     preferred_element_type=jnp.float32)
-            d = jnp.einsum("brt...,rqt->bq...", d_moved, f32[i],
-                           preferred_element_type=jnp.float32)
+            dfactors[0] = mm("bq...,brt...->rqt", zs[0], d_moved)
+            d = mm("brt...,rqt->bq...", d_moved, f32[i])
         else:
-            dfactors[i] = jnp.einsum("brq...,brt...->rqt", zs[i], d_moved,
-                                     preferred_element_type=jnp.float32)
-            d = jnp.einsum("brt...,rqt->brq...", d_moved, f32[i],
-                           preferred_element_type=jnp.float32)
+            dfactors[i] = mm("brq...,brt...->rqt", zs[i], d_moved)
+            d = mm("brt...,rqt->brq...", d_moved, f32[i])
     dx = d.reshape(b, -1)
     return dx, dfactors
 
@@ -487,9 +483,6 @@ def gather_leaves(
 #
 # i.e. the same two contractions as ``chain_fused_forward`` (a first, then
 # b and the rank together).
-
-_HI = jax.lax.Precision.HIGHEST
-
 
 def dot(a, b):
     """``a @ b`` at f32 accuracy (the kernels' fp32 contract on the MXU)."""

@@ -1,5 +1,6 @@
-"""Pallas TPU kernels: fused ket-linear matmul ``y = x · (Σ_k ⊗_j F_jk)``
-(fwd + bwd), plus the host executors of the same tiled algorithm.
+"""Fused ket-linear matmul ``y = x · (Σ_k ⊗_j F_jk)`` (fwd + bwd): Pallas
+kernels, the host executors of the same tiled algorithm, and the compiled
+XLA route that runs it on TPU.
 
 This is the kron_logits streaming pattern with the CE head cut off — the op
 that every ket linear layer (``linear_kind="ket"``: FFN wi/wg/wo, attention
@@ -38,11 +39,16 @@ sees only 2-D and leading-batch matmuls, and the output tile is written as
 transpose turns back into ``(B, prod t)``. Higher orders keep the generic
 bodies (interpret mode).
 
-Off-TPU the public op (``ops.kron_matmul``) routes BOTH directions through
-the host executors below — the identical tile loop and rank-folded
-contractions as one fused XLA computation (no grid emulation). The
+Which code the public op (``ops.kron_matmul``) runs: on TPU, float factor
+stacks take the compiled route at the end of this file
+(``kron_matmul_xla`` / ``kron_matmul_bwd_xla``: the host executors' tile
+loop and rank-folded contractions compiled by XLA at ``Precision.HIGHEST``,
+the tile from a byte budget), which feeds the MXU whole-batch GEMMs where
+the order-2 bodies feed it rank-sized ones; on TPU the Pallas bodies run
+only the int8/fp8 dequant-fused leg (``ops.kron_matmul_quant``). Off-TPU
+both directions run the host executors (no grid emulation). The
 interpret-mode Pallas kernels stay the validation target
-(tests/test_kron_matmul.py pins pallas ≡ host ≡ dense oracle).
+(tests/test_kron_matmul.py pins pallas ≡ host ≡ TPU route ≡ dense oracle).
 """
 
 from __future__ import annotations
@@ -423,3 +429,62 @@ def kron_matmul_bwd_host(
         dfrest = (dfs[1:] if dfrest is None
                   else [a + b for a, b in zip(dfrest, dfs[1:])])
     return dx, [jnp.concatenate(df0_tiles, axis=2), *(dfrest or [])]
+
+
+# ---------------------------------------------------------------------------
+# Compiled route on TPU: the host executors, compiled by XLA
+# ---------------------------------------------------------------------------
+#
+# On the chip the order-2 Pallas bodies above feed the MXU rank-sized
+# operands (an N of rank per first-digit column, a K of rank per second
+# step, the rank axis padded to 128 lanes in VMEM). The same rank-folded
+# chain as two whole-batch GEMMs — ``(B·Πq_rest, q_1) @ (q_1, r·t_1)``, then
+# ``(B·Πt_{<n}, r·q_n) @ (r·q_n, t_n)`` — is what XLA compiles instead, at
+# the kernels' f32 contract (HIGHEST, f32 accumulation). Unquantized factor
+# stacks take this route on TPU; the int8/fp8 leg keeps the Pallas bodies.
+
+# Bytes of the f32 chain intermediate ``(B, r, t1_tile, Πq_rest)`` one tile
+# may hold in HBM. At 256 MiB the rank-8 ket linears of qwen3-1.7b at 4096
+# tokens a call run untiled except the FFN's (d_model -> d_ff in two t_1
+# tiles, d_ff -> d_model in two), and its 8x1024 train step compiles to
+# 12.7 GiB of temporaries for a v5e; 384 MiB takes 14.8 GiB, 512 MiB 15.9.
+CHAIN_BUDGET_BYTES = 256 << 20
+
+
+def chain_t1_tile(rank: int, q_dims: Sequence[int], t_dims: Sequence[int],
+                  n_tokens: int) -> int:
+    """t_1 tile of the compiled route: t_1 itself (untiled) when the widest
+    f32 chain intermediate of ``n_tokens`` rows fits ``CHAIN_BUDGET_BYTES``,
+    else the widest divisor of t_1 whose tile fits (1 at least)."""
+    n = len(q_dims)
+    col = 4 * n_tokens * rank * max(
+        (math.prod(t_dims[1:i]) * math.prod(q_dims[i:]) for i in range(1, n)),
+        default=1)  # bytes per t_1 column, widest chain step
+    return C.largest_divisor_leq(t_dims[0], max(1, CHAIN_BUDGET_BYTES // col))
+
+
+def _route_tile(factors, x) -> int:
+    q_dims, t_dims = C.factor_dims(factors)
+    return chain_t1_tile(factors[0].shape[0], q_dims, t_dims, x.shape[0])
+
+
+def kron_matmul_xla(factors: Sequence[jax.Array], x: jax.Array) -> jax.Array:
+    """The compiled TPU route's forward: :func:`kron_matmul_host` tiled by
+    :func:`chain_t1_tile`, under the ``kron_matmul_fwd`` scope (a profile's
+    op metadata names the layer). Returns ``(B, prod t)`` fp32; the caller
+    slices columns."""
+    with jax.named_scope("kron_matmul_fwd"):
+        return kron_matmul_host(factors, x, t1_block=_route_tile(factors, x))
+
+
+def kron_matmul_bwd_xla(
+    factors: Sequence[jax.Array],
+    x: jax.Array,  # (B, d_in)
+    g: jax.Array,  # (B, prod t) output cotangent, zeros past out_dim
+) -> tuple[jax.Array, list[jax.Array]]:
+    """The compiled TPU route's backward: :func:`kron_matmul_bwd_host` at
+    the same tile, under the ``kron_matmul_bwd`` scope. Returns
+    ``(dx (B, P), [dF_j])``, all fp32."""
+    with jax.named_scope("kron_matmul_bwd"):
+        return kron_matmul_bwd_host(factors, x, g,
+                                    t1_block=_route_tile(factors, x))

@@ -1,12 +1,23 @@
 """Jit'd public op: fused ket-linear matmul with a dedicated backward.
 
-``kron_matmul`` is a ``jax.custom_vjp`` pair: the forward streams F_1 column
-tiles through the rank-folded chain (Pallas kernel on TPU, host executor of
-the identical algorithm elsewhere — interpret-mode grid emulation would cost
-more than the math), and the backward walks the same tiling a second time,
-recomputing the chain intermediates per tile instead of saving them — the
-residuals are just ``(factors, x)``, so the ``(B, r, t_1, Πq_rest)``
-intermediates the XLA chain keeps alive for its autodiff never reach HBM.
+``kron_matmul`` is a ``jax.custom_vjp`` pair: the forward runs F_1 column
+tiles through the rank-folded chain, and the backward walks the same tiling
+a second time, recomputing the chain intermediates per tile instead of
+saving them — the residuals are just ``(factors, x)``, so autodiff keeps no
+``(B, r, t_1, Πq_rest)`` intermediate alive from forward to backward.
+
+Which executor runs the chain:
+
+  * TPU, float factors (ket linears, the Kronecker head's logits): the
+    chain as whole-batch GEMMs that XLA compiles (``kron_matmul_xla`` /
+    ``kron_matmul_bwd_xla``) at ``Precision.HIGHEST`` with f32
+    accumulation, the tile taken from a byte budget on the chain
+    intermediate (``chain_t1_tile``), not from the arguments below;
+  * TPU, int8/fp8 factors (``kron_matmul_quant``): the order-2 Pallas
+    bodies with the dequant fused in;
+  * elsewhere: the host executors, the same tiled chain as one XLA
+    computation at the tile the arguments resolve (interpret-mode grid
+    emulation would cost more than the math).
 
 The plain chain VJP is kept as an oracle and fallback:
 ``set_backward_impl("ref")`` or ``REPRO_KRON_BWD=ref`` route the backward
@@ -20,7 +31,8 @@ factor copies are never materialized up front.
 
 ``t1_block=None`` / ``block_b=None`` (the defaults) resolve from the
 autotune table (op family ``"kron_matmul"``, quantized shapes under their
-payload dtype's key) at trace time.
+payload dtype's key) at trace time, for the Pallas bodies and the host
+executors.
 """
 
 from __future__ import annotations
@@ -36,9 +48,10 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels.kron_matmul.kron_matmul import (
     kron_matmul_bwd_host,
-    kron_matmul_bwd_pallas,
+    kron_matmul_bwd_xla,
     kron_matmul_host,
     kron_matmul_pallas,
+    kron_matmul_xla,
 )
 from repro.kernels.kron_matmul.ref import kron_matmul_ref
 
@@ -93,11 +106,10 @@ def _kron_matmul_local(
     t1_block: Optional[int] = None,
     block_b: Optional[int] = None,
 ) -> jax.Array:
-    t1b, bb = _resolve_blocks(factors, t1_block, block_b)
     if _on_tpu():
-        out = kron_matmul_pallas(
-            list(factors), x, t1_block=t1b, block_b=bb, interpret=False)
+        out = kron_matmul_xla(list(factors), x)
     else:
+        t1b, _ = _resolve_blocks(factors, t1_block, block_b)
         out = kron_matmul_host(list(factors), x, t1_block=t1b)
     return out[:, :out_dim].astype(x.dtype)
 
@@ -176,7 +188,6 @@ def _bwd(out_dim, t1_block, block_b, res, g):
             list(factors), x)
         dfactors, dx = vjp(g.astype(x.dtype))
         return (dfactors, dx)
-    t1b, bb = _resolve_blocks(factors, t1_block, block_b)
     # zero-pad the cotangent past out_dim: those columns were sliced away,
     # so their contribution is identically zero
     T = int(math.prod(f.shape[2] for f in factors))
@@ -184,9 +195,9 @@ def _bwd(out_dim, t1_block, block_b, res, g):
     if T > g32.shape[-1]:
         g32 = jnp.pad(g32, ((0, 0), (0, T - g32.shape[-1])))
     if _on_tpu():
-        dx, dfactors = kron_matmul_bwd_pallas(
-            list(factors), x, g32, t1_block=t1b, block_b=bb, interpret=False)
+        dx, dfactors = kron_matmul_bwd_xla(list(factors), x, g32)
     else:
+        t1b, _ = _resolve_blocks(factors, t1_block, block_b)
         dx, dfactors = kron_matmul_bwd_host(
             list(factors), x, g32, t1_block=t1b)
     dfactors = [df.astype(f.dtype) for df, f in zip(dfactors, factors)]

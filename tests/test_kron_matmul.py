@@ -28,9 +28,12 @@ from repro.core import quant as Q
 from repro.kernels import common as C
 from repro.kernels.kron_matmul import ops as mops
 from repro.kernels.kron_matmul.kron_matmul import (
+    CHAIN_BUDGET_BYTES,
+    chain_t1_tile,
     kron_matmul_bwd_host,
     kron_matmul_bwd_pallas,
     kron_matmul_pallas,
+    kron_matmul_xla,
 )
 from repro.kernels.kron_matmul.ref import kron_matmul_dense_ref, kron_matmul_ref
 
@@ -132,10 +135,10 @@ def test_grad_vs_dense_oracle(order, rank):
 
 
 def test_grad_uses_dedicated_backward(monkeypatch):
-    """On CPU the host executor runs; on TPU the Pallas bwd kernel."""
+    """On CPU the host executor runs; on TPU the compiled XLA route."""
     if mops.get_backward_impl() == "ref":
         pytest.skip("REPRO_KRON_BWD=ref oracle leg: dedicated bwd disabled by design")
-    target = ("kron_matmul_bwd_pallas" if jax.default_backend() == "tpu"
+    target = ("kron_matmul_bwd_xla" if jax.default_backend() == "tpu"
               else "kron_matmul_bwd_host")
     calls = []
     orig = getattr(mops, target)
@@ -175,6 +178,125 @@ def test_ref_fallback_is_chain_vjp(monkeypatch):
         lambda fs: jnp.sum(jnp.cos(kron_matmul_ref(fs, x, 33, tile=2))))(factors)
     _allclose_trees(g_ref_impl, g_chain, tol=2e-5)  # same chain VJP graph
     _allclose_trees(g_kernel, g_chain, tol=1e-4)    # same math, fused exec
+
+
+# ---------------------------------------------------------------------------
+# the compiled TPU route (kron_matmul_xla / _bwd_xla) at the benchmark's
+# shapes: the ket linears and the Kronecker head of qwen3-1.7b-ketlin
+# (chipbench/configs/qwen3-1.7b-ketlin.json), at few tokens
+# ---------------------------------------------------------------------------
+
+KET_SHAPES = {  # name -> (rank, q_dims, t_dims, tokens)
+    "wq": (8, (64, 32), (64, 32), 8),
+    "wk": (8, (64, 32), (32, 32), 8),
+    "wo": (8, (64, 32), (64, 32), 8),
+    "wi": (8, (64, 32), (96, 64), 8),
+    "ffn_wo": (8, (96, 64), (64, 32), 8),
+    "head": (32, (64, 32), (390, 390), 16),
+}
+# the head's dense operator (2048 x 152,100 per rank) is too large for a
+# test: its dense oracle covers every 39th first-digit column
+HEAD_COLS = slice(0, 390, 39)
+
+
+def _ket_case(name):
+    rank, q, t, B = KET_SHAPES[name]
+    key = jax.random.PRNGKey(sum(map(ord, name)))
+    # entries of |y| ~ 1: each factor scaled by 1/sqrt(q_j * sqrt(rank))
+    fs = [jax.random.normal(jax.random.fold_in(key, j), (rank, qj, tj))
+          / math.sqrt(qj * math.sqrt(rank))
+          for j, (qj, tj) in enumerate(zip(q, t))]
+    x = jax.random.normal(jax.random.fold_in(key, 7), (B, math.prod(q)))
+    return fs, x, math.prod(t)
+
+
+def _close(got, want, tol=1e-5):
+    """max |got - want| within ``tol`` of max |want| (f32 rounding over
+    contractions thousands long)."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape, (got.shape, want.shape)
+    err, scale = np.abs(got - want).max(), np.abs(want).max()
+    assert err <= tol * scale, (err, scale)
+
+
+def _head_dense_cols(fs, x):
+    """Dense oracle of the head's ``HEAD_COLS`` first-digit columns."""
+    sub = [C.slice_factor_t(fs[0], HEAD_COLS), fs[1]]
+    return kron_matmul_dense_ref(sub, x, sub[0].shape[2] * fs[1].shape[2])
+
+
+@pytest.mark.parametrize("name", sorted(KET_SHAPES))
+def test_xla_route_forward_matches_oracles(name):
+    fs, x, T = _ket_case(name)
+    got = kron_matmul_xla(fs, x)
+    _close(got, kron_matmul_pallas(fs, x, t1_block=16, block_b=8))
+    if name == "head":
+        t1, t2 = fs[0].shape[2], fs[1].shape[2]
+        cols = got.reshape(-1, t1, t2)[:, HEAD_COLS].reshape(x.shape[0], -1)
+        _close(cols, _head_dense_cols(fs, x))
+    else:
+        _close(got, kron_matmul_dense_ref(fs, x, T))
+
+
+@pytest.mark.parametrize("name", sorted(KET_SHAPES))
+def test_xla_route_grads_match_oracles(name, monkeypatch):
+    """The op on its TPU route (``_on_tpu`` forced): forward and custom-VJP
+    gradients through kron_matmul_xla / _bwd_xla, against the
+    interpret-mode Pallas backward and the dense oracle's VJP."""
+    if mops.get_backward_impl() == "ref":
+        pytest.skip("REPRO_KRON_BWD=ref oracle leg: dedicated bwd disabled by design")
+    fs, x, T = _ket_case(name)
+    w = jax.random.normal(jax.random.PRNGKey(5), (x.shape[0], T))
+    calls = []
+    for fn in ("kron_matmul_xla", "kron_matmul_bwd_xla"):
+        orig = getattr(mops, fn)
+        monkeypatch.setattr(mops, fn, lambda *a, _o=orig, _n=fn, **k:
+                            (calls.append(_n), _o(*a, **k))[1])
+    monkeypatch.setattr(mops, "_on_tpu", lambda: True)
+    # a t1 tile of 1 is ignored on this route (the tile comes from the budget)
+    loss, (dfs, dx) = jax.value_and_grad(
+        lambda f, xx: jnp.sum(w * mops.kron_matmul(f, xx, T, 1, 8)),
+        argnums=(0, 1))(fs, x)
+    assert calls == ["kron_matmul_xla", "kron_matmul_bwd_xla"], calls
+    np.testing.assert_allclose(float(loss), float(jnp.sum(w * kron_matmul_xla(fs, x))),
+                               rtol=1e-6)
+    dx_p, dfs_p = kron_matmul_bwd_pallas(fs, x, w, t1_block=16, block_b=8)
+    for got, want in zip([dx, *dfs], [dx_p, *dfs_p]):
+        _close(got, want)
+    if name == "head":
+        return  # the dense oracle's VJP needs the whole head operator
+    _, vjp = jax.vjp(lambda f, xx: kron_matmul_dense_ref(f, xx, T), fs, x)
+    dfs_d, dx_d = vjp(w)
+    for got, want in zip([dx, *dfs], [dx_d, *dfs_d]):
+        _close(got, want)
+
+
+@pytest.mark.parametrize("rank,q,t,tokens,want", [
+    # the 8x1024 train step's calls, 4096 tokens
+    (8, (64, 32), (64, 32), 4096, 64),  # wq/wo: 256 MiB, fits exactly
+    (8, (64, 32), (32, 32), 4096, 32),  # wk/wv
+    (8, (64, 32), (96, 64), 4096, 48),  # wi/wg: 384 MiB untiled
+    (8, (96, 64), (64, 32), 4096, 32),  # ffn wo: 512 MiB untiled
+    # serving: the head at 16 rows runs untiled (26 MB), not 130 tiles of 3
+    (32, (64, 32), (390, 390), 16, 390),
+    # the head at 4096 rows: 16 MiB a column, widest divisor of 390 <= 16
+    (32, (64, 32), (390, 390), 4096, 15),
+    # one column over budget still runs, one column at a time
+    (8, (64, 32), (64, 32), 4096 * 128, 1),
+    # order 3: the widest step is (B, r, t1, t2, q3), not (B, r, t1, q2·q3)
+    (2, (2, 2, 8), (4, 8, 2), 1 << 18, 2),
+])
+def test_chain_t1_tile_rule(rank, q, t, tokens, want):
+    """Untiled when the f32 chain intermediate fits the budget; otherwise
+    the widest divisor of t1 whose tile fits."""
+    tile = chain_t1_tile(rank, q, t, tokens)
+    assert tile == want
+    assert t[0] % tile == 0
+    col = 4 * tokens * rank * max(
+        math.prod(t[1:i]) * math.prod(q[i:]) for i in range(1, len(q)))
+    wider = [d for d in range(tile + 1, t[0] + 1) if t[0] % d == 0]
+    assert tile == 1 or tile * col <= CHAIN_BUDGET_BYTES
+    assert all(d * col > CHAIN_BUDGET_BYTES for d in wider)
 
 
 # ---------------------------------------------------------------------------

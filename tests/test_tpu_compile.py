@@ -1,5 +1,8 @@
 """Compile every main-path kernel for a TPU v5e chip, at qwen3-1.7b widths.
 
+Also the ket linears' compiled XLA route (no kernel) with the memory it
+takes.
+
 Nothing runs: each kernel is lowered with ``interpret=False`` and compiled
 ahead of time for a v5e chip that is described, not attached, so the TPU
 compiler's refusals (block tiling, unsupported in-kernel relayouts, VMEM)
@@ -34,7 +37,9 @@ from repro.kernels.kron_gather.kron_gather import (
     kron_gather_pallas,
 )
 from repro.kernels.kron_logits.kron_logits import kron_ce_bwd_pallas, kron_ce_pallas
+from repro.kernels.kron_matmul import ops as mops
 from repro.kernels.kron_matmul.kron_matmul import (
+    CHAIN_BUDGET_BYTES,
     kron_matmul_bwd_pallas,
     kron_matmul_pallas,
 )
@@ -82,6 +87,18 @@ def compile_tpu(one_chip, no_persistent_cache):
                            text)
         assert names, "no Mosaic kernel in the program"
         assert all(re.fullmatch(rf"{kernel}(\.\d+)?", n) for n in names), names
+    return run
+
+
+@pytest.fixture
+def compile_tpu_xla(one_chip, no_persistent_cache):
+    """Compile ``fn`` for the described chip where it must hold no Mosaic
+    kernel at all; returns the compiled program."""
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert 'custom_call_target="tpu_custom_call"' not in compiled.as_text()
+        return compiled
     return run
 
 
@@ -197,6 +214,40 @@ def test_kron_matmul_bwd(compile_tpu, name):
         interpret=False),
         _f32(*fs[0]), _f32(*fs[1]), _f32(TOKENS, d_in), _f32(TOKENS, d_out),
         kernel="kron_matmul_bwd")
+
+
+# the ket linears' route on TPU (ops.kron_matmul on a TPU backend: the
+# rank-folded chain compiled by XLA) at a train microbatch of 4096 tokens,
+# with the config's compute dtype for the activations
+ROUTE_TOKENS = 4096
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "value_and_grad"])
+@pytest.mark.parametrize("name", sorted(LINEARS))
+def test_kron_matmul_xla_route(compile_tpu_xla, monkeypatch, name, grad):
+    """No Mosaic kernel; the fusions carry the layer's scope names; the
+    temporaries stay within the chain's byte budget per live tile."""
+    monkeypatch.setattr(mops, "_on_tpu", lambda: True)
+    fs = LINEARS[name]
+    d_in = math.prod(f[1] for f in fs)
+    d_out = math.prod(f[2] for f in fs)
+
+    def fwd(f0, f1, x):
+        return mops.kron_matmul([f0, f1], x, d_out)
+
+    def loss(f0, f1, x):
+        return jnp.sum(fwd(f0, f1, x).astype(jnp.float32) ** 2)
+
+    fn = jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    compiled = compile_tpu_xla(fn, _f32(*fs[0]), _f32(*fs[1]),
+                               ((ROUTE_TOKENS, d_in), CFG.dtype))
+    text = compiled.as_text()
+    assert "kron_matmul_fwd" in text
+    assert ("kron_matmul_bwd" in text) == grad
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # one chain tile forward; the backward adds the tile's cotangent
+    assert temp <= (2 if grad else 1) * CHAIN_BUDGET_BYTES + (1 << 20), temp
+
 
 
 # serving decode read: 8 slots at max_len 1056 (66 pages of 16 tokens),
