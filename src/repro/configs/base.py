@@ -33,6 +33,17 @@ class ModelConfig:
     local_window: int = 2048
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # rotary scaling (DeepSeek-V2's ``rope_scaling``): "none" | "yarn". YaRN
+    # blends θ^(-2i/d) with θ^(-2i/d)/rope_factor over the correction range
+    # of beta_fast/beta_slow at original_max_position_embeddings; MLA's
+    # softmax scale gains yarn_get_mscale(rope_factor, mscale_all_dim)².
+    rope_scaling: str = "none"
+    rope_factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
     # MLP flavor
     mlp_type: str = "swiglu"  # swiglu | gelu | geglu
@@ -41,8 +52,21 @@ class ModelConfig:
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # expert-parallel (mesh all-to-all) path only
     router_dtype: Any = jnp.float32
+    # DeepSeek-V2 routing (its config.json keys): renormalise the top-k
+    # gates to sum to one, else scale them by ``routed_scaling_factor``
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the routed experts this device holds: the first ``n_experts_held``
+    # (0 = all ``n_experts``). The router keeps all ``n_experts`` outputs;
+    # the layer computes its held experts' part.
+    n_experts_held: int = 0
+    # leading layers whose FFN is a dense SwiGLU of width
+    # ``intermediate_size`` instead of the MoE (DeepSeek-V2's
+    # ``first_k_dense_replace``); they run unrolled before the scanned groups
+    first_k_dense_replace: int = 0
+    intermediate_size: int = 0
 
     # MLA (DeepSeek-style latent attention)
     mla: bool = False
@@ -165,6 +189,16 @@ class ModelConfig:
                 "hybrid": ("rglru", "rglru", "local_attn"),
             }[self.family]
             object.__setattr__(self, "layer_pattern", pattern)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def dense_ffn_width(self) -> int:
+        """FFN width of the leading dense layers."""
+        return self.intermediate_size or self.d_ff
 
     @property
     def q_heads_per_kv(self) -> int:

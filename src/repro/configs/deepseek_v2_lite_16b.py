@@ -1,12 +1,26 @@
-"""deepseek-v2-lite-16b [moe] — 27L d_model=2048 16H, MLA kv_lora=512,
-d_ff(expert)=1408, vocab=102400, 2 shared + 64 routed experts, top-6.
+"""deepseek-v2-lite-16b [moe] — DeepSeek-V2-Lite as published
+[hf:deepseek-ai/DeepSeek-V2-Lite config.json; arXiv:2405.04434].
 
-[arXiv:2405.04434]. The assignment line lists both "64e top-6" and
-"160 routed"; we follow the primary "64 routed + 2 shared, top-6" (matches
-the HF DeepSeek-V2-Lite config). See DESIGN.md §5.
+27 layers, hidden 2048, 16 heads. MLA without q-LoRA: kv_lora_rank 512,
+qk_nope_head_dim 128 (``head_dim``), qk_rope_head_dim 64 (``rope_head_dim``),
+v_head_dim 128. Layer 0 is dense (``first_k_dense_replace`` 1, SwiGLU of
+``intermediate_size`` 10944); layers 1–26 are MoE: 64 routed experts of
+width 1408 (``moe_intermediate_size``, here ``d_ff``), top-6 greedy over a
+softmax, ``norm_topk_prob`` false, ``routed_scaling_factor`` 1, plus 2 shared
+experts. YaRN rope: factor 40 over 4096 original positions, beta_fast 32,
+beta_slow 1, mscale = mscale_all_dim = 0.707, θ 10000. Vocabulary 102400,
+RMSNorm eps 1e-6.
+
+Departure: DeepSeek-V2 de-interleaves the rope dims of q and k before the
+rotate-half rotation; that is a fixed permutation of the rope projections'
+output columns, which random weights absorb, so it is not applied here.
 """
 
 from repro.configs.base import ModelConfig
+
+_YARN = dict(rope_scaling="yarn", rope_factor=40.0,
+             original_max_position_embeddings=4096, beta_fast=32.0,
+             beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707)
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -21,9 +35,15 @@ CONFIG = ModelConfig(
     n_experts=64,
     n_shared_experts=2,
     top_k=6,
+    norm_topk_prob=False,
+    routed_scaling_factor=1.0,
+    first_k_dense_replace=1,
+    intermediate_size=10944,
     mla=True,
     kv_lora_rank=512,
     rope_head_dim=64,
+    rope_theta=10000.0,
+    **_YARN,
 )
 
 SMOKE = ModelConfig(
@@ -37,11 +57,16 @@ SMOKE = ModelConfig(
     d_ff=48,
     vocab_size=1024,
     n_experts=4,
+    n_experts_held=2,
     n_shared_experts=1,
     top_k=2,
+    norm_topk_prob=False,
+    first_k_dense_replace=1,
+    intermediate_size=96,
     mla=True,
     kv_lora_rank=16,
     rope_head_dim=8,
     embedding_rank=2,
     head_rank=2,
+    **{**_YARN, "rope_factor": 4.0, "original_max_position_embeddings": 64},
 )

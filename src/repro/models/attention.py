@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import (apply_rope, dense_init, linear_init,
-                                 linear_opts, out_proj, qkv_proj, rmsnorm)
+                                 linear_opts, out_proj, qkv_proj, rmsnorm,
+                                 yarn_get_mscale)
 
 NEG = jnp.float32(-1e30)
 
@@ -30,6 +31,7 @@ def flash_attention(
     kv_valid_len: jax.Array | None = None,  # (B,) mask for padded caches
     q_offset: jax.Array | None = None,  # (B,) absolute position of query 0
     kv_pos: jax.Array | None = None,  # (B, Skv) absolute key positions; <0 invalid
+    scale: float | None = None,  # softmax scale; None = Dh^-0.5
 ) -> jax.Array:
     """Chunked-softmax attention; never materializes the (Sq, Skv) matrix.
 
@@ -43,7 +45,8 @@ def flash_attention(
     _, Skv, KVH, _ = k.shape
     Dv = v.shape[-1]
     G = H // KVH
-    qf = (q.astype(jnp.float32) * (Dh ** -0.5)).astype(q.dtype)
+    scale = Dh ** -0.5 if scale is None else scale
+    qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qf = qf.reshape(B, Sq, KVH, G, Dh)
 
     C = min(chunk, Skv)
@@ -233,6 +236,16 @@ def cross_attention_block(params, cfg, x, enc_k, enc_v, chunk: int = 1024):
 # MLA (DeepSeek-V2 latent attention)
 # ---------------------------------------------------------------------------
 
+def mla_softmax_scale(cfg) -> float:
+    """(qk_nope + qk_rope)^-1/2, times yarn_get_mscale(factor,
+    mscale_all_dim)² under YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    scale = (cfg.head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.rope_scaling == "yarn" and cfg.mscale_all_dim:
+        m = yarn_get_mscale(cfg.rope_factor, cfg.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
 def init_mla(key, cfg, dtype=None) -> dict:
     dtype = dtype or cfg.param_dtype
     d, H, Dh = cfg.d_model, cfg.num_heads, cfg.head_dim
@@ -268,7 +281,7 @@ def mla_absorbed_q(params, cfg, x, cos, sin):
     """x (B, S, d) -> (q_abs (B, S, H, L), q_rope (B, S, H, R)).
 
     Queries for the absorbed-matmul score against a latent cache:
-    score = q_abs·c + q_rope·k_rope at scale (head_dim + R)^-0.5."""
+    score = q_abs·c + q_rope·k_rope at :func:`mla_softmax_scale`."""
     dt = cfg.dtype
     Dh = cfg.head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
@@ -293,31 +306,9 @@ def mla_block(params, cfg, x, cos, sin, *, chunk: int = 1024):
 
     qq = jnp.concatenate([q_nope, q_rope], axis=-1)
     kk = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:3] + (R,))], axis=-1)
-    out = flash_attention(qq, kk, v, causal=True, chunk=chunk)
+    out = flash_attention(qq, kk, v, causal=True, chunk=chunk,
+                          scale=mla_softmax_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
-
-
-def mla_decode(params, cfg, x_tok, cache_c, cache_krope, cache_len, cos, sin):
-    """Absorbed-matmul MLA decode over the latent cache.
-
-    x_tok (B, d); cache_c (B, S, L); cache_krope (B, S, R).
-    score = (q_nope·W_uk)·c + q_rope·k_rope; ctx = (Σ α c)·W_uv.
-    """
-    dt = cfg.dtype
-    Dh, R = cfg.head_dim, cfg.rope_head_dim
-    q_abs, q_rope = mla_absorbed_q(params, cfg, x_tok[:, None], cos, sin)
-    q_abs, q_rope = q_abs[:, 0], q_rope[:, 0]
-
-    scale = (Dh + R) ** -0.5
-    s = jnp.einsum("bhl,bsl->bhs", q_abs, cache_c, preferred_element_type=jnp.float32)
-    s += jnp.einsum("bhr,bsr->bhs", q_rope, cache_krope, preferred_element_type=jnp.float32)
-    s *= scale
-    pos = jnp.arange(cache_c.shape[1])
-    s = jnp.where((pos[None, :] < cache_len[:, None])[:, None], s, NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    ctx_l = jnp.einsum("bhs,bsl->bhl", p.astype(dt), cache_c)
-    ctx = jnp.einsum("bhl,lhk->bhk", ctx_l, params["w_uv"].astype(dt))
-    return jnp.einsum("bhk,hkd->bd", ctx, params["wo"].astype(dt))
 
 
 def mla_cache_step(params, cfg, x_tok, cos, sin):

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 __all__ = ["rmsnorm", "init_rmsnorm", "dense_init", "apply_rope", "rope_angles",
            "softcap", "linear_init", "linear_apply", "qkv_proj", "out_proj",
-           "is_ket_param", "linear_opts"]
+           "is_ket_param", "linear_opts", "yarn_get_mscale", "yarn_of"]
 
 
 def init_rmsnorm(dim: int, dtype=jnp.float32) -> dict:
@@ -117,12 +117,59 @@ def out_proj(p, o: jax.Array, dtype, d_model: int, *, tile=None,
     return jnp.einsum("...hk,hkd->...d", o, p.astype(dtype))
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """positions (..., S) -> cos/sin of shape (..., S, head_dim//2)."""
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """DeepSeek-V2's attention-scale correction for a rope stretched by
+    ``scale``: 0.1·mscale·ln(scale) + 1 (1 when nothing is stretched)."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_of(cfg):
+    """The YaRN parameters of a config as a static tuple, None without it."""
+    if getattr(cfg, "rope_scaling", "none") != "yarn":
+        return None
+    return (cfg.rope_factor, cfg.original_max_position_embeddings,
+            cfg.beta_fast, cfg.beta_slow, cfg.mscale, cfg.mscale_all_dim)
+
+
+def _yarn_freqs(head_dim: int, theta: float, yarn) -> tuple[jax.Array, float]:
+    """DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``: θ^(-2i/d) kept for
+    the high-frequency dims, divided by the factor for the low ones, blended
+    by a linear ramp over the correction range; cos/sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    factor, orig, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    extra = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    inter = extra / factor
+    keep = 1.0 - ramp
+    freqs = inter * (1 - keep) + extra * keep
+    scale = yarn_get_mscale(factor, mscale) / yarn_get_mscale(factor, mscale_all_dim)
+    return freqs, scale
+
+
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                yarn=None) -> tuple[jax.Array, jax.Array]:
+    """positions (..., S) -> cos/sin of shape (..., S, head_dim//2).
+    ``yarn``: :func:`yarn_of`'s tuple for YaRN-scaled frequencies."""
+    half = head_dim // 2
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.cos(ang), jnp.sin(ang)
+    freqs, scale = _yarn_freqs(head_dim, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

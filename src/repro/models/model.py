@@ -45,9 +45,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
 
 
 def serve_step_fn(params, cfg: ModelConfig, cache: dict, tokens: jax.Array):
+    """One decode step -> (logits, new cache). (The serving engine calls
+    serve/decode.serve_step, which adds a MoE config's expert row counts.)"""
     if cfg.family == "encdec":
         return ED.encdec_serve_step(params, cfg, cache, tokens)
-    return D.serve_step(params, cfg, cache, tokens)
+    return D.serve_step(params, cfg, cache, tokens)[:2]
 
 
 def prefill_chunk_fn(params, cfg: ModelConfig, cache: dict, tokens: jax.Array,
@@ -57,7 +59,7 @@ def prefill_chunk_fn(params, cfg: ModelConfig, cache: dict, tokens: jax.Array,
     new cache)."""
     if cfg.family == "encdec":
         raise NotImplementedError("chunked prefill targets LM decode paths")
-    return D.prefill_step(params, cfg, cache, tokens, lens)
+    return D.prefill_step(params, cfg, cache, tokens, lens)[:2]
 
 
 def prefill_fn(params, cfg: ModelConfig, batch: dict):

@@ -1,15 +1,25 @@
 """Mixture-of-Experts FFN (DeepSeek-V2-lite / Moonlight style).
 
-Shared experts + routed experts with top-k softmax gating. Two execution
-paths sharing one sort-based capacity dispatcher:
+Shared experts + routed experts with top-k softmax gating (DeepSeek-V2's
+``MoEGate``: softmax over every routed expert in f32, greedy top-k, the
+gates renormalised only under ``norm_topk_prob``, else scaled by
+``routed_scaling_factor``). Two execution paths:
 
-  * **single-shard** (CPU tests, no mesh): dispatch buffer holds all experts;
-  * **expert-parallel** (ambient mesh with a "model" axis): tokens are
-    sub-sharded along the model axis, dispatched into per-destination
-    capacity slots, exchanged with ``lax.all_to_all``, FFN'd by the local
-    expert shard, exchanged back and combined. Dropless up to the capacity
-    factor; overflow tokens are dropped (standard GShard semantics) and
-    counted in the aux metrics.
+  * **held experts, dropless** (no mesh): the layer holds the routed experts
+    ``0 .. experts_held - 1`` (all of them by default; another device's
+    share is the same layer over its own experts' weights, with the
+    router's columns ordered so that its experts come first). It routes over all ``n_experts``; the rows routed to
+    its held experts are sorted by expert and run as one grouped product
+    (``lax.ragged_dot``). No capacity applies and nothing is dropped, so a
+    token's output does not depend on what else is in the batch. With fewer
+    experts held than routed, the layer computes its share of the result:
+    what the other shares' experts would add is not computed here.
+  * **expert-parallel** (ambient mesh with a "model" axis, every expert
+    held): tokens are sub-sharded along the model axis, dispatched into
+    per-destination capacity slots, exchanged with ``lax.all_to_all``,
+    FFN'd by the local expert shard, exchanged back and combined. Dropless
+    up to the capacity factor; overflow tokens are dropped (standard GShard
+    semantics) and counted in the aux metrics.
 
 The shared experts run *outside* shard_map as a fused dense FFN so they keep
 ordinary tensor parallelism over the model axis.
@@ -28,11 +38,12 @@ from repro.parallel import meshctx
 
 
 def init_moe(key, cfg, dtype=None) -> dict:
+    """Router over all ``n_experts``; weights of the held experts only."""
     dtype = dtype or cfg.param_dtype
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, E = cfg.d_model, cfg.d_ff, cfg.experts_held
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], (d, E), jnp.float32, fan_in=d),
+        "router": dense_init(ks[0], (d, cfg.n_experts), jnp.float32, fan_in=d),
         "wi": dense_init(ks[1], (E, d, f), dtype, fan_in=d),
         "wg": dense_init(ks[2], (E, d, f), dtype, fan_in=d),
         "wo": dense_init(ks[3], (E, f, d), dtype, fan_in=f),
@@ -44,10 +55,16 @@ def init_moe(key, cfg, dtype=None) -> dict:
 
 def _route(params, cfg, x_flat):
     """x (N, d) -> (expert_ids (N,k), gates (N,k), aux_loss scalar)."""
-    logits = jnp.einsum("nd,de->ne", x_flat.astype(jnp.float32), params["router"])
+    # the router is f32 in the published model: a TPU's default f32 matmul
+    # would round both operands to bf16 and move near-tied experts
+    logits = jnp.einsum("nd,de->ne", x_flat.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, cfg.top_k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    elif cfg.routed_scaling_factor != 1.0:
+        gates = gates * cfg.routed_scaling_factor
     # load-balance aux (Switch-style): E * Σ_e fraction_e · prob_e
     E = cfg.n_experts
     frac = jnp.mean(
@@ -80,6 +97,35 @@ def _expert_ffn(params, x, dtype):
     return jnp.einsum("ecf,efd->ecd", h, params["wo"].astype(dtype))
 
 
+def moe_held(params, cfg, x_flat, valid=None):
+    """Dropless routed part of the layer over the held experts.
+
+    x_flat (N, d); ``valid`` (N,) bool leaves padding rows out of the expert
+    products. Returns (out (N, d) of the held experts only, aux scalar,
+    rows (experts_held,) int32: the rows each held expert received)."""
+    N, d = x_flat.shape
+    k, E_h, dt = cfg.top_k, cfg.experts_held, cfg.dtype
+    ids, gates, aux = _route(params, cfg, x_flat)
+    held = ids < E_h
+    if valid is not None:
+        held &= valid[:, None]
+    # rows of held experts sorted by expert; the rest sort last, past every
+    # group, and ragged_dot leaves them out
+    key = jnp.where(held, ids, E_h).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.zeros((E_h + 1,), jnp.int32).at[key].add(1)[:E_h]
+    xs = x_flat.astype(dt)[order // k]
+    with jax.named_scope("moe_experts"):
+        h = jax.lax.ragged_dot(xs, params["wi"].astype(dt), rows)
+        g = jax.lax.ragged_dot(xs, params["wg"].astype(dt), rows)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * h, params["wo"].astype(dt), rows)
+    y = jnp.zeros_like(y).at[order].set(y).reshape(N, k, d)
+    y = jnp.where(held[..., None], y, 0)
+    w = jnp.where(held, gates, 0.0).astype(dt)
+    out = jnp.einsum("nkd,nk->nd", y, w, preferred_element_type=jnp.float32)
+    return out.astype(dt), aux, rows
+
+
 def _moe_local(params, cfg, x_flat, n_local_experts: int, a2a_axis: str | None):
     """Per-shard MoE: route -> dispatch -> (a2a) -> expert FFN -> (a2a) -> combine."""
     N, d = x_flat.shape
@@ -110,11 +156,13 @@ def _moe_local(params, cfg, x_flat, n_local_experts: int, a2a_axis: str | None):
     return out, aux, drop_frac
 
 
-def moe_block(params, cfg, x):
+def moe_block(params, cfg, x, valid=None):
     """x (B, S, d) -> (B, S, d), plus metrics dict.
 
     Uses expert-parallel all_to_all when an ambient mesh with a "model" axis
-    exists and E divides evenly; otherwise the single-shard path.
+    exists, every expert is held here and E divides evenly; otherwise the
+    held-expert dropless path, whose metrics carry ``moe_rows`` (rows each
+    held expert received; ``valid`` (B, S) leaves padding out).
     """
     B, S, d = x.shape
     mesh = meshctx.get_mesh()
@@ -129,6 +177,7 @@ def moe_block(params, cfg, x):
     n_local_tokens = (B // dp) * S
     use_ep = (
         M > 1
+        and cfg.experts_held == cfg.n_experts
         and cfg.n_experts % M == 0
         and n_local_tokens % M == 0
         and n_local_tokens >= M
@@ -160,11 +209,15 @@ def moe_block(params, cfg, x):
             check_vma=False,
         )
         out, aux, drop = inner_sm(x, params["router"], params["wi"], params["wg"], params["wo"])
+        metrics = {"moe_aux": aux, "moe_drop": drop}
     else:
         flat = x.reshape(B * S, d)
-        out, aux, drop = _moe_local(params, cfg, flat, cfg.n_experts, None)
+        out, aux, rows = moe_held(params, cfg, flat,
+                                  None if valid is None else valid.reshape(-1))
         out = out.reshape(B, S, d)
+        metrics = {"moe_aux": aux, "moe_drop": jnp.zeros((), jnp.float32),
+                   "moe_rows": rows}
 
     if cfg.n_shared_experts:
         out = out + ffn(params["shared"], x, "swiglu", cfg.dtype)
-    return out, {"moe_aux": aux, "moe_drop": drop}
+    return out, metrics

@@ -3,7 +3,10 @@
 Layers are grouped by the config's ``layer_pattern`` (e.g. recurrentgemma's
 ("rglru", "rglru", "local_attn")); full groups are *stacked* and executed
 under ``lax.scan`` (one trace per pattern position — keeps HLO size and
-compile time independent of depth), remainder layers run unrolled.
+compile time independent of depth), remainder layers run unrolled. A MoE
+config's ``first_k_dense_replace`` leading layers ("lead": attention with a
+dense SwiGLU FFN of width ``intermediate_size``, DeepSeek-V2's layer 0) run
+unrolled before the groups; configs without them carry no "lead" key.
 
 Three modes share the block definitions:
   * train:   full-sequence forward -> fused CE loss (logits never materialized)
@@ -27,7 +30,7 @@ from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import ssm as S
 from repro.models.common import (init_rmsnorm, linear_opts, rmsnorm,
-                                 rope_angles)
+                                 rope_angles, yarn_of)
 
 KINDS_WITH_FFN = {"attn", "local_attn", "rglru"}
 
@@ -36,7 +39,10 @@ KINDS_WITH_FFN = {"attn", "local_attn", "rglru"}
 # Per-layer init
 # ---------------------------------------------------------------------------
 
-def init_layer(key, cfg: ModelConfig, kind: str) -> dict:
+def init_layer(key, cfg: ModelConfig, kind: str, dense_ffn: bool = False) -> dict:
+    """One layer's parameters; ``dense_ffn`` gives a "moe_attn" layer a dense
+    SwiGLU FFN of ``cfg.dense_ffn_width`` in place of its MoE (a leading
+    dense layer)."""
     ks = jax.random.split(key, 4)
     p: dict[str, Any] = {"ln1": init_rmsnorm(cfg.d_model, cfg.param_dtype)}
     lin = dict(kind=cfg.linear_kind, order=cfg.linear_order, rank=cfg.linear_rank,
@@ -49,7 +55,11 @@ def init_layer(key, cfg: ModelConfig, kind: str) -> dict:
     elif kind == "moe_attn":
         p["attn"] = A.init_mla(ks[0], cfg) if cfg.mla else A.init_attention(ks[0], cfg)
         p["ln2"] = init_rmsnorm(cfg.d_model, cfg.param_dtype)
-        p["moe"] = M.init_moe(ks[1], cfg)
+        if dense_ffn:
+            p["ffn"] = F.init_ffn(ks[1], cfg.d_model, cfg.dense_ffn_width,
+                                  "swiglu", cfg.param_dtype, **lin)
+        else:
+            p["moe"] = M.init_moe(ks[1], cfg)
     elif kind == "ssm":
         p["ssm"] = S.init_ssm(ks[0], cfg)
     elif kind == "rglru":
@@ -64,8 +74,9 @@ def init_layer(key, cfg: ModelConfig, kind: str) -> dict:
 
 def init_lm(key, cfg: ModelConfig) -> dict:
     pattern = cfg.layer_pattern
-    n_groups = cfg.num_layers // len(pattern)
-    rem = cfg.num_layers % len(pattern)
+    n_lead = cfg.first_k_dense_replace
+    n_groups = (cfg.num_layers - n_lead) // len(pattern)
+    rem = (cfg.num_layers - n_lead) % len(pattern)
     keys = jax.random.split(key, 4)
 
     def stack(pos: int, kind: str):
@@ -82,6 +93,11 @@ def init_lm(key, cfg: ModelConfig) -> dict:
         ],
         "final_norm": init_rmsnorm(cfg.d_model, cfg.param_dtype),
     }
+    if n_lead:
+        params["lead"] = [
+            init_layer(jax.random.fold_in(keys[2], (1 << 20) + i), cfg, "moe_attn",
+                       dense_ffn=True)
+            for i in range(n_lead)]
     if not getattr(cfg, "tie_embeddings", False):
         params["head"] = init_head(keys[3], head_for(cfg))
     return params
@@ -126,9 +142,9 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, cos, sin, *, want_cache: bool
             if want_cache:
                 cache = {"k": k, "v": v}
         x = x + o
-        moe_out, metrics = M.moe_block(p["moe"], cfg, rmsnorm(p["ln2"], x))
-        x = x + moe_out
-        aux = metrics["moe_aux"]
+        y, metrics = ffn_or_moe(p, cfg, rmsnorm(p["ln2"], x))
+        x = x + y
+        aux = metrics.get("moe_aux", aux)
     elif kind == "ssm":
         x = x + S.ssm_block(p["ssm"], cfg, h, scan_chunk=scan_chunk)
         if want_cache:
@@ -143,6 +159,15 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, cos, sin, *, want_cache: bool
     else:
         raise ValueError(kind)
     return x, aux, cache
+
+
+def ffn_or_moe(p, cfg: ModelConfig, h, valid=None):
+    """The FFN half of a "moe_attn" layer: a leading layer's dense SwiGLU
+    (metrics {}) or the MoE (``moe_block``'s metrics). h (B, S, d)."""
+    if "ffn" in p:
+        return F.ffn(p["ffn"], h, "swiglu", cfg.dtype,
+                     dims=(cfg.d_model, cfg.dense_ffn_width), **linear_opts(cfg)), {}
+    return M.moe_block(p["moe"], cfg, h, valid)
 
 
 def _ssm_prefill_cache(p, cfg, h_in):
@@ -194,8 +219,9 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_prefix=None, want_cache=F
     if extra_prefix is not None:
         x = jnp.concatenate([extra_prefix.astype(cfg.dtype), x], axis=1)
     B, Stot = x.shape[0], x.shape[1]
-    cos, sin = rope_angles(jnp.arange(Stot), cfg.head_dim, cfg.rope_theta)
-    cos_r, sin_r = rope_angles(jnp.arange(Stot), cfg.rope_head_dim, cfg.rope_theta)
+    yarn = yarn_of(cfg)
+    cos, sin = rope_angles(jnp.arange(Stot), cfg.head_dim, cfg.rope_theta, yarn)
+    cos_r, sin_r = rope_angles(jnp.arange(Stot), cfg.rope_head_dim, cfg.rope_theta, yarn)
     pattern = cfg.layer_pattern
 
     def group_fn(x, group_params):
@@ -213,6 +239,17 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_prefix=None, want_cache=F
     group_fn = _remat_wrap(group_fn, cfg.remat)
 
     auxs = jnp.zeros((), jnp.float32)
+    lead_caches = []
+    for p_layer in params.get("lead", []):
+        cs = (cos_r, sin_r) if cfg.mla else (cos, sin)
+        x, a, cache = _remat_wrap(
+            lambda x, p, cs=cs: apply_block(p, cfg, "moe_attn", x, *cs,
+                                            want_cache=want_cache,
+                                            attn_chunk=attn_chunk),
+            cfg.remat)(x, p_layer)
+        auxs = auxs + a
+        lead_caches.append(cache)
+
     caches_stacked = None
     if params["groups"]:
         stacked = tuple(params["groups"])
@@ -235,6 +272,8 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_prefix=None, want_cache=F
 
     x = rmsnorm(params["final_norm"], x)
     caches = {"groups": caches_stacked, "rem": rem_caches} if want_cache else None
+    if want_cache and "lead" in params:
+        caches["lead"] = lead_caches
     return x, auxs, caches
 
 

@@ -20,6 +20,13 @@ no parent and are not in the profiler's trace.
 
 ``SPAN_NAMES`` lists every name the program records: the contract that
 readers of the records and of a trace rely on.
+
+Counters ride on spans as attrs. A MoE config's ``engine.sync`` carries
+``moe_rows``, the rows the held routed experts received in the tick
+(summed over experts and layers; padding and idle slots send none), and
+``moe_rows_max``, the most rows any one held expert received (summed over
+layers). They come back in the sampled tokens' device→host transfer; other
+configs record neither.
 """
 
 from __future__ import annotations
@@ -36,7 +43,8 @@ SPAN_NAMES = (
     "engine.admit",     # deadlines and admission from the queue
     "engine.grow",      # page growth, copy-on-write and preemption
     "engine.dispatch",  # the tick's device inputs and the model call, up to its return
-    "engine.sync",      # sampling and the finiteness check: the host waits for the device
+    "engine.sync",      # sampling and the finiteness check: the host waits for the device;
+                        # MoE configs: moe_rows, moe_rows_max
     "engine.emit",      # per-slot emission and on_token callbacks
     "engine.publish",   # full pages hashed into the prefix cache
     "request.queue",    # submit or requeue to admission; uid, requeues

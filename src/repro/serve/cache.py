@@ -92,15 +92,16 @@ def init_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int) -> d
 
 def _assemble(cfg: ModelConfig, batch: int, layer_fn) -> dict:
     pattern = cfg.layer_pattern
-    n_groups = cfg.num_layers // len(pattern)
-    rem = cfg.num_layers % len(pattern)
+    n_lead = cfg.first_k_dense_replace
+    n_groups = (cfg.num_layers - n_lead) // len(pattern)
+    rem = (cfg.num_layers - n_lead) % len(pattern)
 
     def stacked(kind):
         one = layer_fn(kind)
         return jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (n_groups,) + x.shape), one)
 
-    return {
+    cache = {
         "groups": [stacked(kind) for kind in pattern] if n_groups else [],
         "rem": [layer_fn(pattern[i % len(pattern)]) for i in range(rem)],
         # PER-SLOT positions: each batch slot decodes at its own offset, so a
@@ -108,6 +109,9 @@ def _assemble(cfg: ModelConfig, batch: int, layer_fn) -> dict:
         # slot without disturbing its neighbours (serve/engine.py).
         "step": jnp.zeros((batch,), jnp.int32),
     }
+    if n_lead:  # leading dense layers: the same attention cache as moe_attn
+        cache["lead"] = [layer_fn("moe_attn") for _ in range(n_lead)]
+    return cache
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:

@@ -34,18 +34,19 @@ from repro.core.embedding import embed_lookup
 from repro.kernels.flash_attn import ops as FOPS
 from repro.models import attention as A
 from repro.models import ffn as F
-from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import ssm as S
 from repro.models.common import (linear_opts, out_proj, qkv_proj, rmsnorm,
-                                 rope_angles)
-from repro.models.transformer import lm_logits_last
+                                 rope_angles, yarn_of)
+from repro.models.transformer import ffn_or_moe, lm_logits_last
 from repro.parallel import meshctx
 from repro.serve.cache import gather_pages
 from repro.serve.cache import init_cache  # noqa: F401  (compat re-export)
 from repro.serve.cache import init_layer_cache  # noqa: F401  (compat re-export)
 
 NEG = jnp.float32(-1e30)
+# most bytes of f32 scores one block of the paged MLA read may hold
+MLA_SCORE_BUDGET = 128 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -119,10 +120,9 @@ def mla_decode_attention(cfg, p_attn, x_tok, cache_c, cache_krope, slot, valid_l
     """Absorbed MLA decode with a seq-sharded latent cache. slot/valid (B,)."""
     dt = cfg.dtype
     c_new, kr_new = A.mla_cache_step(p_attn, cfg, x_tok, cos, sin)
-    Dh, R_ = cfg.head_dim, cfg.rope_head_dim
     q_abs, q_rope = A.mla_absorbed_q(p_attn, cfg, x_tok[:, None], cos, sin)
     q_abs, q_rope = q_abs[:, 0], q_rope[:, 0]
-    scale = (Dh + R_) ** -0.5
+    scale = A.mla_softmax_scale(cfg)
 
     def partial_attn(qa, qr, cc, ckr, vlen, pos_offset):
         s = jnp.einsum("bhl,bsl->bhs", qa, cc, preferred_element_type=jnp.float32)
@@ -242,15 +242,65 @@ def paged_kv_decode_attention(cfg, q, k_new, v_new, pool_k, pool_v, ptab, step):
     return out.astype(q.dtype), pool_k, pool_v
 
 
+def paged_mla_attend(cfg, p_attn, q_abs, q_rope, pool_c, pool_krope, ptab, step, hi):
+    """Absorbed MLA read of paged latent pools, blocked over each slot's
+    logical view with an online softmax.
+
+    q_abs (B, C, H, L), q_rope (B, C, H, R): queries at positions step + i
+    (step (B,)); a query attends to every position at or before its own.
+    ``hi`` (B,): one past the last position a valid query holds; blocks
+    wholly past the largest are not read. A block gathers its pages alone
+    and its f32 scores stay within ``MLA_SCORE_BUDGET``, so neither the
+    whole (B, max_len, L) view nor (B, C, H, max_len) scores are made.
+    Returns (B, C, d) through w_uv and wo."""
+    dt = cfg.dtype
+    B, C, H, L = q_abs.shape
+    ps, NP = pool_c.shape[1], ptab.shape[1]
+    bp = int(max(1, min(NP, MLA_SCORE_BUDGET // (B * H * C * 4 * ps))))
+    n_blocks = -(-NP // bp)
+    if n_blocks * bp != NP:  # pad with the trash page: masked, past max_len
+        ptab = jnp.pad(ptab, ((0, 0), (0, n_blocks * bp - NP)))
+    S = bp * ps
+    qpos = step[:, None] + jnp.arange(C)  # (B, C)
+    scale = A.mla_softmax_scale(cfg)
+
+    def body(i, carry):
+        m, l, acc = carry
+        pids = jax.lax.dynamic_slice_in_dim(ptab, i * bp, bp, axis=1)
+        cc = pool_c[pids].reshape(B, S, L)
+        ckr = pool_krope[pids].reshape(B, S, -1)
+        s = jnp.einsum("bchl,bsl->bhcs", q_abs, cc, preferred_element_type=jnp.float32)
+        s += jnp.einsum("bchr,bsr->bhcs", q_rope, ckr,
+                        preferred_element_type=jnp.float32)
+        s *= scale
+        kpos = i * S + jnp.arange(S)
+        s = jnp.where((kpos[None, None] <= qpos[:, :, None])[:, None], s, NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhcs,bsl->bhcl", p.astype(dt), cc, preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    n = jnp.minimum((jnp.max(hi) + S - 1) // S, n_blocks)
+    init = (jnp.full((B, H, C), NEG), jnp.zeros((B, H, C)),
+            jnp.zeros((B, H, C, L)))
+    _, l, acc = jax.lax.fori_loop(0, n, body, init)
+    ctx_l = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(dt)
+    ctx = jnp.einsum("bhcl,lhk->bchk", ctx_l, p_attn["w_uv"].astype(dt))
+    return jnp.einsum("bchk,hkd->bcd", ctx, p_attn["wo"].astype(dt))
+
+
 def paged_mla_decode_attention(cfg, p_attn, x_tok, pool_c, pool_krope, ptab, step, cos, sin):
-    """Absorbed MLA decode over paged latent pools (gather read)."""
+    """Absorbed MLA decode over paged latent pools (blocked read)."""
     c_new, kr_new = A.mla_cache_step(p_attn, cfg, x_tok, cos, sin)
     pool_c = _page_write(pool_c, ptab, step, c_new)
     pool_krope = _page_write(pool_krope, ptab, step, kr_new)
-    cc = gather_pages(pool_c, ptab)  # (B, NP*ps, L)
-    ckr = gather_pages(pool_krope, ptab)
-    out = A.mla_decode(p_attn, cfg, x_tok, cc, ckr, step + 1, cos, sin)
-    return out, pool_c, pool_krope
+    q_abs, q_rope = A.mla_absorbed_q(p_attn, cfg, x_tok[:, None], cos, sin)
+    out = paged_mla_attend(cfg, p_attn, q_abs, q_rope, pool_c, pool_krope, ptab,
+                           step, step + 1)
+    return out[:, 0], pool_c, pool_krope
 
 
 # ---------------------------------------------------------------------------
@@ -258,8 +308,10 @@ def paged_mla_decode_attention(cfg, p_attn, x_tok, pool_c, pool_krope, ptab, ste
 # ---------------------------------------------------------------------------
 
 def decode_block(p, cfg: ModelConfig, kind: str, x, cache, step, cos, sin,
-                 cos_r=None, sin_r=None, ptab=None):
-    """x (B, d) one token at per-slot positions step (B,); returns (x, cache)."""
+                 cos_r=None, sin_r=None, ptab=None, live=None):
+    """x (B, d) one token at per-slot positions step (B,); returns (x, cache,
+    rows): the rows each held expert of a MoE layer received (``live``
+    (B, 1) leaves idle slots out), None for a layer without held experts."""
     dt = cfg.dtype
     h = rmsnorm(p["ln1"], x)
     opts = linear_opts(cfg)
@@ -291,7 +343,7 @@ def decode_block(p, cfg: ModelConfig, kind: str, x, cache, step, cos, sin,
         x = x + out_proj(p["attn"]["wo"], o, dt, cfg.d_model, **opts)
         x = x + F.ffn(p["ffn"], rmsnorm(p["ln2"], x)[:, None], cfg.mlp_type, dt,
                       dims=(cfg.d_model, cfg.d_ff), **opts)[:, 0]
-        return x, new_cache
+        return x, new_cache, None
     if kind == "moe_attn":
         if cfg.mla:
             if paged:
@@ -323,61 +375,99 @@ def decode_block(p, cfg: ModelConfig, kind: str, x, cache, step, cos, sin,
                 new_cache = {"k": ck, "v": cv}
             o = out_proj(p["attn"]["wo"], o, dt, cfg.d_model, **opts)
         x = x + o
-        moe_out, _ = M.moe_block(p["moe"], cfg, rmsnorm(p["ln2"], x)[:, None])
-        return x + moe_out[:, 0], new_cache
+        y, metrics = ffn_or_moe(p, cfg, rmsnorm(p["ln2"], x)[:, None], live)
+        return x + y[:, 0], new_cache, metrics.get("moe_rows")
     if kind == "ssm":
         out, new_cache = S.ssm_decode_step(p["ssm"], cfg, h, cache)
-        return x + out, new_cache
+        return x + out, new_cache, None
     if kind == "rglru":
         out, new_cache = R.rglru_decode_step(p["rec"], cfg, h, cache)
         x = x + out
         x = x + F.ffn(p["ffn"], rmsnorm(p["ln2"], x)[:, None], "geglu", dt,
                       dims=(cfg.d_model, cfg.d_ff), **opts)[:, 0]
-        return x, new_cache
+        return x, new_cache, None
     raise ValueError(kind)
 
 
-def serve_step(params, cfg: ModelConfig, cache: dict, tokens: jax.Array):
-    """tokens (B,) -> (logits (B, vocab), new cache). One decode step at
-    per-slot positions cache["step"] (B,). Cache layout (dense vs paged) is
-    detected from the pytree."""
-    step = cache["step"]  # (B,)
-    ptab = cache.get("ptab")
-    ecfg = embedding_for(cfg)
-    x = embed_lookup(ecfg, params["embed"], tokens).astype(cfg.dtype)
-    cos, sin = rope_angles(step[:, None], cfg.head_dim, cfg.rope_theta)  # (B,1,half)
-    cos_r, sin_r = rope_angles(step[:, None], cfg.rope_head_dim, cfg.rope_theta)
+def _sum_rows(rows):
+    """Sum of the per-expert row counts given; None where there are none."""
+    rows = [r for r in rows if r is not None]
+    return sum(rows) if rows else None
+
+
+def _run_layers(params, cfg: ModelConfig, cache: dict, x, block):
+    """The leading layers, the scanned groups and the remainder, in order.
+
+    ``block(p, kind, x, layer_cache)`` returns (x, cache, rows): the rows
+    each held expert of the layer received, or None for a layer without
+    held experts. Returns (x, the new layer caches, rows summed over
+    layers, or None where no layer holds experts)."""
     pattern = cfg.layer_pattern
+    rows = []
+
+    new_lead = []
+    for p_layer, c in zip(params.get("lead", []), cache.get("lead", [])):
+        x, nc, r = block(p_layer, "moe_attn", x, c)
+        new_lead.append(nc)
+        rows.append(r)
 
     new_groups = []
     if params["groups"]:
         def scan_body(x, xs):
             per_group_params, per_group_cache = xs
-            new_caches = []
+            new_caches, acc = [], []
             for pos_i, kind in enumerate(pattern):
-                x, nc = decode_block(per_group_params[pos_i], cfg, kind, x,
-                                     per_group_cache[pos_i], step, cos, sin,
-                                     cos_r, sin_r, ptab)
+                x, nc, r = block(per_group_params[pos_i], kind, x, per_group_cache[pos_i])
                 new_caches.append(nc)
-            return x, tuple(new_caches)
+                acc.append(r)
+            return x, (tuple(new_caches), _sum_rows(acc))
 
-        x, stacked_new = jax.lax.scan(
+        x, (stacked_new, r) = jax.lax.scan(
             scan_body, x, (tuple(params["groups"]), tuple(cache["groups"])))
         new_groups = list(stacked_new)
+        rows.append(None if r is None else jnp.sum(r, axis=0))
 
     new_rem = []
     for i, p_layer in enumerate(params["rem"]):
-        kind = pattern[i % len(pattern)]
-        x, nc = decode_block(p_layer, cfg, kind, x, cache["rem"][i], step, cos, sin,
-                             cos_r, sin_r, ptab)
+        x, nc, r = block(p_layer, pattern[i % len(pattern)], x, cache["rem"][i])
         new_rem.append(nc)
+        rows.append(r)
 
+    new_cache = {"groups": new_groups, "rem": new_rem}
+    if "lead" in cache:
+        new_cache["lead"] = new_lead
+    return x, new_cache, _sum_rows(rows)
+
+
+def serve_step(params, cfg: ModelConfig, cache: dict, tokens: jax.Array):
+    """tokens (B,) -> (logits (B, vocab), new cache). One decode step at
+    per-slot positions cache["step"] (B,). Cache layout (dense vs paged) is
+    detected from the pytree. A config whose MoE layers run the held-expert
+    path also returns the rows each held expert received, summed over
+    layers (experts_held,) int32; a paged slot that maps no page (idle)
+    sends no rows."""
+    step = cache["step"]  # (B,)
+    ptab = cache.get("ptab")
+    ecfg = embedding_for(cfg)
+    x = embed_lookup(ecfg, params["embed"], tokens).astype(cfg.dtype)
+    yarn = yarn_of(cfg)
+    cos, sin = rope_angles(step[:, None], cfg.head_dim, cfg.rope_theta, yarn)  # (B,1,half)
+    cos_r, sin_r = rope_angles(step[:, None], cfg.rope_head_dim, cfg.rope_theta, yarn)
+    live = None  # MoE: idle slots keep all-zero (trash) page rows
+    if ptab is not None and cfg.n_experts:
+        live = ptab[:, :1] != 0
+
+    def block(p, kind, x, c):
+        return decode_block(p, cfg, kind, x, c, step, cos, sin, cos_r, sin_r,
+                            ptab, live)
+
+    x, new_cache, rows = _run_layers(params, cfg, cache, x, block)
     x = rmsnorm(params["final_norm"], x)
     logits = lm_logits_last(params, cfg, x)
-    new_cache = {"groups": new_groups, "rem": new_rem, "step": step + 1}
+    new_cache["step"] = step + 1
     if ptab is not None:
         new_cache["ptab"] = ptab
-    return logits, new_cache
+    return (logits, new_cache) if rows is None else (logits, new_cache, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -440,26 +530,26 @@ def _chunk_attention(cfg, kind, p_attn, h, cache, ptab, step, lens, cos, sin):
 
 def _chunk_mla_attention(cfg, p_attn, h, cache, ptab, step, lens, cos_r, sin_r):
     """Absorbed MLA over a chunk: scatter latents, then causal-masked scores
-    against the slot's logical latent view. h (B, C, d) -> (B, C, d)."""
+    against the slot's logical latent view (paged: :func:`paged_mla_attend`,
+    blocked). h (B, C, d) -> (B, C, d)."""
     dt = cfg.dtype
     C = h.shape[1]
-    Dh, R_ = cfg.head_dim, cfg.rope_head_dim
     c_new, kr_new = A.mla_latents(p_attn, cfg, h, cos_r, sin_r)
+    q_abs, q_rope = A.mla_absorbed_q(p_attn, cfg, h, cos_r, sin_r)
 
     if "c_pages" in cache:
         pc = _page_write_chunk(cache["c_pages"], ptab, step, lens, c_new)
         pkr = _page_write_chunk(cache["krope_pages"], ptab, step, lens, kr_new)
-        cc, ckr = gather_pages(pc, ptab), gather_pages(pkr, ptab)
-        new_cache = {"c_pages": pc, "krope_pages": pkr}
-    else:
-        pos_w = step[:, None] + jnp.arange(C)
-        valid = jnp.arange(C)[None] < lens[:, None]
-        cc = _scatter_chunk(cache["c"], pos_w, valid, c_new)
-        ckr = _scatter_chunk(cache["krope"], pos_w, valid, kr_new)
-        new_cache = {"c": cc, "krope": ckr}
+        out = paged_mla_attend(cfg, p_attn, q_abs, q_rope, pc, pkr, ptab,
+                               step, step + lens)
+        return out, {"c_pages": pc, "krope_pages": pkr}
 
-    q_abs, q_rope = A.mla_absorbed_q(p_attn, cfg, h, cos_r, sin_r)
-    scale = (Dh + R_) ** -0.5
+    pos_w = step[:, None] + jnp.arange(C)
+    valid = jnp.arange(C)[None] < lens[:, None]
+    cc = _scatter_chunk(cache["c"], pos_w, valid, c_new)
+    ckr = _scatter_chunk(cache["krope"], pos_w, valid, kr_new)
+    new_cache = {"c": cc, "krope": ckr}
+    scale = A.mla_softmax_scale(cfg)
 
     s = jnp.einsum("bchl,bsl->bhcs", q_abs, cc, preferred_element_type=jnp.float32)
     s += jnp.einsum("bchr,bsr->bhcs", q_rope, ckr, preferred_element_type=jnp.float32)
@@ -477,7 +567,8 @@ def _chunk_mla_attention(cfg, p_attn, h, cache, ptab, step, lens, cos_r, sin_r):
 def prefill_block(p, cfg: ModelConfig, kind: str, x, cache, ptab, step, lens,
                   cos, sin, cos_r=None, sin_r=None):
     """x (B, C, d) chunk continuing per-slot caches at offsets step (B,);
-    rows past lens_b are garbage (ignored downstream). Returns (x, cache)."""
+    rows past lens_b are garbage (ignored downstream). Returns (x, cache,
+    rows) as :func:`decode_block`."""
     dt = cfg.dtype
     opts = linear_opts(cfg)
     h = rmsnorm(p["ln1"], x)
@@ -487,7 +578,7 @@ def prefill_block(p, cfg: ModelConfig, kind: str, x, cache, ptab, step, lens,
         x = x + out_proj(p["attn"]["wo"], o, dt, cfg.d_model, **opts)
         x = x + F.ffn(p["ffn"], rmsnorm(p["ln2"], x), cfg.mlp_type, dt,
                       dims=(cfg.d_model, cfg.d_ff), **opts)
-        return x, new_cache
+        return x, new_cache, None
     if kind == "moe_attn":
         if cfg.mla:
             o, new_cache = _chunk_mla_attention(cfg, p["attn"], h, cache, ptab,
@@ -497,17 +588,18 @@ def prefill_block(p, cfg: ModelConfig, kind: str, x, cache, ptab, step, lens,
                                             step, lens, cos, sin)
             o = out_proj(p["attn"]["wo"], o, dt, cfg.d_model, **opts)
         x = x + o
-        moe_out, _ = M.moe_block(p["moe"], cfg, rmsnorm(p["ln2"], x))
-        return x + moe_out, new_cache
+        valid = jnp.arange(x.shape[1])[None] < lens[:, None]
+        y, metrics = ffn_or_moe(p, cfg, rmsnorm(p["ln2"], x), valid)
+        return x + y, new_cache, metrics.get("moe_rows")
     if kind == "ssm":
         out, new_cache = S.ssm_prefill_chunk(p["ssm"], cfg, h, lens, cache)
-        return x + out, new_cache
+        return x + out, new_cache, None
     if kind == "rglru":
         out, new_cache = R.rglru_prefill_chunk(p["rec"], cfg, h, lens, cache)
         x = x + out
         x = x + F.ffn(p["ffn"], rmsnorm(p["ln2"], x), "geglu", dt,
                       dims=(cfg.d_model, cfg.d_ff), **opts)
-        return x, new_cache
+        return x, new_cache, None
     raise ValueError(kind)
 
 
@@ -518,8 +610,10 @@ def prefill_step(params, cfg: ModelConfig, cache: dict, tokens: jax.Array,
 
     Returns (logits (B, vocab) at each slot's LAST VALID chunk position —
     meaningful only for slots whose prompt ends in this chunk — and the new
-    cache with step advanced by lens). One call == one engine tick; a
-    P-token prompt prefills in ⌈P/C⌉ ticks.
+    cache with step advanced by lens), and for a held-expert MoE config the
+    rows each held expert received from valid positions, as
+    :func:`serve_step`.
+    One call == one engine tick; a P-token prompt prefills in ⌈P/C⌉ ticks.
     """
     step = cache["step"]  # (B,)
     ptab = cache.get("ptab")
@@ -527,38 +621,20 @@ def prefill_step(params, cfg: ModelConfig, cache: dict, tokens: jax.Array,
     ecfg = embedding_for(cfg)
     x = embed_lookup(ecfg, params["embed"], tokens).astype(cfg.dtype)  # (B,C,d)
     pos = step[:, None] + jnp.arange(C)  # (B, C)
-    cos, sin = rope_angles(pos, cfg.head_dim, cfg.rope_theta)  # (B,C,half)
-    cos_r, sin_r = rope_angles(pos, cfg.rope_head_dim, cfg.rope_theta)
-    pattern = cfg.layer_pattern
+    yarn = yarn_of(cfg)
+    cos, sin = rope_angles(pos, cfg.head_dim, cfg.rope_theta, yarn)  # (B,C,half)
+    cos_r, sin_r = rope_angles(pos, cfg.rope_head_dim, cfg.rope_theta, yarn)
 
-    new_groups = []
-    if params["groups"]:
-        def scan_body(x, xs):
-            per_group_params, per_group_cache = xs
-            new_caches = []
-            for pos_i, kind in enumerate(pattern):
-                x, nc = prefill_block(per_group_params[pos_i], cfg, kind, x,
-                                      per_group_cache[pos_i], ptab, step, lens,
-                                      cos, sin, cos_r, sin_r)
-                new_caches.append(nc)
-            return x, tuple(new_caches)
+    def block(p, kind, x, c):
+        return prefill_block(p, cfg, kind, x, c, ptab, step, lens, cos, sin,
+                             cos_r, sin_r)
 
-        x, stacked_new = jax.lax.scan(
-            scan_body, x, (tuple(params["groups"]), tuple(cache["groups"])))
-        new_groups = list(stacked_new)
-
-    new_rem = []
-    for i, p_layer in enumerate(params["rem"]):
-        kind = pattern[i % len(pattern)]
-        x, nc = prefill_block(p_layer, cfg, kind, x, cache["rem"][i], ptab, step,
-                              lens, cos, sin, cos_r, sin_r)
-        new_rem.append(nc)
-
+    x, new_cache, rows = _run_layers(params, cfg, cache, x, block)
     x = rmsnorm(params["final_norm"], x)
     last = jnp.clip(lens - 1, 0, C - 1)
     x_last = x[jnp.arange(B), last]  # (B, d) each slot's last valid position
     logits = lm_logits_last(params, cfg, x_last)
-    new_cache = {"groups": new_groups, "rem": new_rem, "step": step + lens}
+    new_cache["step"] = step + lens
     if ptab is not None:
         new_cache["ptab"] = ptab
-    return logits, new_cache
+    return (logits, new_cache) if rows is None else (logits, new_cache, rows)

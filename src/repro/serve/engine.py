@@ -103,6 +103,7 @@ from repro.configs.base import ModelConfig
 from repro.core.quant import dequantize_params, quantize_params
 from repro.fault import PreemptionHandler, StragglerWatchdog
 from repro.models import model as MD
+from repro.serve import decode as D
 from repro.serve.cache import (PAGED_KINDS, TRASH_PAGE, PageAllocator,
                                PrefixCache, copy_page, logical_pages,
                                pages_needed, reset_slot, slot_axes)
@@ -114,14 +115,15 @@ __all__ = ["Request", "ServingEngine", "DrainResult", "EngineStepError",
 # module-level jitted entry points (cfg is a hashable frozen dataclass):
 # every engine over the same config shares one compilation cache instead of
 # re-tracing per instance
+# held-expert MoE configs' steps also return the rows each expert received
 @functools.partial(jax.jit, static_argnums=(0,))
 def _jit_step(cfg, params, cache, tokens):
-    return MD.serve_step_fn(params, cfg, cache, tokens)
+    return D.serve_step(params, cfg, cache, tokens)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _jit_prefill(cfg, params, cache, tokens, lens):
-    return MD.prefill_chunk_fn(params, cfg, cache, tokens, lens)
+    return D.prefill_step(params, cfg, cache, tokens, lens)
 
 
 class EngineStepError(RuntimeError):
@@ -743,20 +745,28 @@ class ServingEngine:
         else:
             self._cur_tokens[s] = tok
 
-    def _sample(self, logits) -> np.ndarray:
+    def _sample(self, logits) -> jax.Array:
         if self.greedy:
-            return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            return jnp.argmax(logits, axis=-1)
         self.key, k = jax.random.split(self.key)
-        return np.asarray(jax.random.categorical(k, logits), np.int32)
+        return jax.random.categorical(k, logits)
 
-    def _guarded_emit(self, logits, emitting: list[int]):
+    def _guarded_emit(self, logits, emitting: list[int], rows=None):
         """Sample and emit for ``emitting`` slots, quarantining any slot
         whose logits row is not finite (max over the vocab catches both NaN
-        and ±inf in one cheap (B,) transfer)."""
-        with obs.span("engine.sync"):
+        and ±inf in one cheap (B,) transfer). ``rows`` (MoE configs: rows
+        each held expert received this tick) comes back in the sampled
+        tokens' transfer and is recorded on the ``engine.sync`` span as
+        ``moe_rows`` (their sum) and ``moe_rows_max`` (the most any held
+        expert received)."""
+        with obs.span("engine.sync") as sp:
             if self._injector is not None:
                 logits = self._injector.corrupt_logits(self, logits, emitting)
             nxt = self._sample(logits)
+            if rows is not None:
+                nxt, rows = jax.device_get((nxt, rows))
+                sp.set(moe_rows=int(rows.sum()), moe_rows_max=int(rows.max()))
+            nxt = np.asarray(nxt, np.int32)
             finite = np.isfinite(np.asarray(jnp.max(logits, axis=-1)))
         with obs.span("engine.emit"):
             for s in emitting:
@@ -793,7 +803,7 @@ class ServingEngine:
                     was_decoding[s] = True
                     toks[s, 0] = self._cur_tokens[s]
                     lens[s] = 1
-            logits, self.cache = self._model_call(
+            logits, self.cache, *rows = self._model_call(
                 lambda: self._prefill(self.params, self.cache,
                                       jnp.asarray(toks), jnp.asarray(lens)))
         self.prefill_ticks += 1
@@ -807,12 +817,15 @@ class ServingEngine:
                 continue  # still mid-prompt: logits row not meaningful yet
             # piggybacked decode, or prompt done (first token samples here)
             emitting.append(s)
-        self._guarded_emit(logits, emitting)
+        self._guarded_emit(logits, emitting, *rows)
 
     def _decode_tick(self):
         with obs.span("engine.dispatch"):
-            toks = jnp.asarray(self._cur_tokens)
-            logits, self.cache = self._model_call(
+            # a host copy: on the CPU the step's input may alias the numpy
+            # buffer it came from (``jnp.array`` too), and stepwise prefill
+            # rewrites ``_cur_tokens`` below while the step may still read it
+            toks = jnp.asarray(self._cur_tokens.copy())
+            logits, self.cache, *rows = self._model_call(
                 lambda: self._step(self.params, self.cache, toks))
         self.decode_ticks += 1
         emitting = []
@@ -826,7 +839,7 @@ class ServingEngine:
                 self._cur_tokens[s] = self.slot_pending[s].popleft()
                 continue
             emitting.append(s)
-        self._guarded_emit(logits, emitting)
+        self._guarded_emit(logits, emitting, *rows)
 
     def step(self):
         """One engine tick: one jitted model call for the whole batch (or a

@@ -19,7 +19,7 @@ import pytest
 # modules whose per-test compile churn is large enough to hit the XLA:CPU
 # JIT corruption on their own (each test uses a fresh config, so per-test
 # clearing costs no recompiles)
-_CLEAR_EVERY_TEST = {"test_serving_conformance"}
+_CLEAR_EVERY_TEST = {"test_serving_conformance", "test_deepseek_v2"}
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -51,12 +51,18 @@ def test_moe_block_matches_manual_dense():
 
 
 def test_moe_drop_fraction_reported():
+    """The expert-parallel path's capacity dispatcher (run here without its
+    exchange) drops past capacity and says so; the dropless layer that
+    serves without a mesh drops nothing at the same setting."""
     cfg = _moe_cfg(capacity_factor=0.25)  # force drops
     params = M.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
-    out, metrics = M.moe_block(params, cfg, x)
-    assert float(metrics["moe_drop"]) > 0.0
+    out, _, drop = M._moe_local(params, cfg, x.reshape(-1, 16), cfg.n_experts, None)
+    assert float(drop) > 0.0
     assert bool(jnp.all(jnp.isfinite(out)))
+    out, metrics = M.moe_block(params, cfg, x)
+    assert float(metrics["moe_drop"]) == 0.0
+    assert int(metrics["moe_rows"].sum()) == x.shape[0] * x.shape[1] * cfg.top_k
 
 
 # ---------------------------------------------------------------------------

@@ -18,15 +18,14 @@ from repro.serve.cache import identity_ptab as _alloc_identity_ptab
 from repro.serve.engine import Request, ServingEngine
 
 # cache kinds: attn (dense GQA), local_attn (ring buffer), mla (latent
-# cache; ample expert capacity so token dropping can't split the paths),
+# cache over a dropless MoE, so no token's output depends on the batch),
 # ssm (O(1) recurrent state — paged mode keeps it dense by design)
 KINDS = {
     "attn": dict(family="dense", num_heads=4, num_kv_heads=2, qk_norm=True),
     "local_attn": dict(family="dense", layer_pattern=("local_attn",),
                        num_heads=4, num_kv_heads=2, local_window=5),
     "mla": dict(family="moe", mla=True, num_heads=4, num_kv_heads=4,
-                n_experts=4, top_k=2, capacity_factor=16.0,
-                kv_lora_rank=16, rope_head_dim=8),
+                n_experts=4, top_k=2, kv_lora_rank=16, rope_head_dim=8),
     "ssm": dict(family="ssm", num_heads=4, num_kv_heads=4),
 }
 
@@ -133,8 +132,7 @@ def test_moe_gqa_qknorm_decode_matches_full_forward():
     cfg = ModelConfig(
         name="conf-moe-qknorm", family="moe", num_layers=2, d_model=32,
         d_ff=96, vocab_size=64, head_dim=8, num_heads=4, num_kv_heads=2,
-        qk_norm=True, n_experts=4, top_k=2, capacity_factor=16.0,
-        embedding_kind="word2ketxs", embedding_rank=4, head_kind="kron",
+        qk_norm=True, n_experts=4, top_k=2, embedding_kind="word2ketxs", embedding_rank=4, head_kind="kron",
         head_rank=4, dtype=jnp.float32, param_dtype=jnp.float32, remat="none")
     params = MD.init_params(jax.random.PRNGKey(0), cfg)
     B, T, C = 2, 6, 4

@@ -49,6 +49,61 @@ def test_engine_matches_direct_decode(setup):
     assert req.output == ref
 
 
+# run in a fresh process: late in a long pytest worker XLA:CPU can return
+# garbage logits (conftest.py), which would read as the fault checked here
+_STEPWISE_ALIGNED = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_smoke
+from repro.models import model as MD
+from repro.serve.engine import Request, ServingEngine
+from test_serving_engine import _direct_greedy
+cfg = get_smoke("granite-3-2b", dtype=jnp.float32)
+params = MD.init_params(jax.random.PRNGKey(0), cfg)
+prompt = [5, 17, 333, 42, 7, 11, 250, 3]
+out = {"ref": _direct_greedy(cfg, params, prompt, 4), "engine": [], "aliased": False}
+for _ in range(3):
+    eng = ServingEngine(cfg, params, batch_slots=1, max_len=64,
+                        cache_mode="dense", prefill_mode="stepwise")
+    buf = np.zeros(32, np.int32)
+    off = (-buf.ctypes.data % 64) // buf.itemsize
+    eng._cur_tokens = buf[off:off + 1]
+    assert eng._cur_tokens.ctypes.data % 64 == 0
+    step = eng._step
+    def watched(p, cache, toks):
+        lo = eng._cur_tokens.ctypes.data
+        out["aliased"] |= lo <= toks.unsafe_buffer_pointer() < lo + eng._cur_tokens.nbytes
+        return step(p, cache, toks)
+    eng._step = watched
+    req = Request(uid=1, prompt=prompt, max_new_tokens=4)
+    eng.submit(req)
+    eng.run_until_drained()
+    out["engine"].append(req.output)
+print(json.dumps(out))
+"""
+
+
+def test_stepwise_prefill_feeds_the_tokens_it_was_given():
+    """Stepwise prefill rewrites the host's next-token buffer right after
+    dispatching a step. On the CPU an aligned numpy buffer can back the
+    step's input without a copy, so the step must get a copy of it: with
+    the buffer 64-byte aligned, no step's input lies in the buffer, and
+    outputs match direct decode every time."""
+    import json
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([os.path.join(os.path.dirname(here), "src"), here]))
+    p = subprocess.run([sys.executable, "-c", _STEPWISE_ALIGNED], capture_output=True,
+                       text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert not out["aliased"]
+    assert out["engine"] == [out["ref"]] * 3
+
+
 def test_engine_batches_multiple_requests(setup):
     cfg, params = setup
     eng = ServingEngine(cfg, params, batch_slots=2, max_len=64)

@@ -272,3 +272,54 @@ def test_paged_combine(compile_tpu):
     compile_tpu(lambda o, m, l: combine_splits_pallas(o, m, l, interpret=False),
                 _f32(*part, DH), _f32(*part, 1), _f32(*part, 1),
                 kernel="paged_attn_combine")
+
+
+# DeepSeek-V2-Lite's serving steps as the longdoc cell runs them: one
+# chip's share (14 layers, 8 of 64 routed experts held), 12 slots × 16384
+# positions of paged latent cache, the cell's prefill chunk of 64
+DS_CFG = get_config("deepseek-v2-lite-16b", num_layers=14, n_experts_held=8,
+                    prefill_chunk=64)
+DS_SLOTS, DS_MAX_LEN = 12, 16384
+# the cell's HBM: 5.17 GiB of f32 weights and 2.96 GiB of latent pool in,
+# 2.96 GiB of new pool out, of v5e's ~15.75 GiB; the temporaries get the rest
+DS_TEMP_BUDGET = 5 << 30
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_deepseek_longdoc_steps(one_chip, no_persistent_cache, monkeypatch, step):
+    """The decode and chunk steps compile for the chip with their kernels
+    (no Mosaic kernel of this repo but the embedding's gather), the grouped
+    expert product, and temporaries within what the cell leaves free."""
+    from repro.models import model as MD
+    from repro.serve import decode as D
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, B = DS_CFG, DS_SLOTS
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: MD.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = placed(jax.eval_shape(lambda: MD.init_cache(cfg, B, DS_MAX_LEN, paged=True)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if step == "decode":
+        fn = lambda p, c, t: D.serve_step(p, cfg, c, t)
+        args = (params, cache, i32(B))
+    else:
+        fn = lambda p, c, t, n: D.prefill_step(p, cfg, c, t, n)
+        args = (params, cache, i32(B, cfg.prefill_chunk), i32(B))
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r'%([\w.-]+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+                             text))
+    # XLA lowers lax.ragged_dot to its own Mosaic calls ("ragged-dot-*")
+    ours = {k for k in kernels if not k.startswith("ragged-dot")}
+    assert ours == {"kron_gather_fwd"}, kernels
+    assert "moe_experts" in text and kernels != ours
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < DS_TEMP_BUDGET, ma.temp_size_in_bytes
+    assert ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes \
+        < int(15.75 * (1 << 30))
